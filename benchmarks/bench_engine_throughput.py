@@ -123,15 +123,15 @@ def main():
                          "adaptive policy's mask stream must not compile "
                          "one executable per distinct mask on the timed "
                          "path")
-    ap.add_argument("--compile-cache-dir", default="",
-                    help="persistent XLA compilation cache directory "
-                         "(DESIGN.md §9); empty disables. A second bench "
-                         "invocation against the same dir re-traces but "
-                         "loads executables from disk instead of "
-                         "recompiling")
+    ap.add_argument("--compile-cache", action="store_true",
+                    help="enable the persistent XLA compilation cache "
+                         "(DESIGN.md §9; JAX_COMPILATION_CACHE_DIR when "
+                         "set, else .jax_cache/ in the checkout). A second "
+                         "bench invocation re-traces but loads executables "
+                         "from disk instead of recompiling")
     ap.add_argument("--assert-cache-replay", action="store_true",
                     help="hard gate for warmed-replay CI: with "
-                         "--compile-cache-dir pre-populated by an earlier "
+                         "--compile-cache pre-populated by an earlier "
                          "identical invocation, this process must hit the "
                          "disk cache (> 0 hits) and compile nearly "
                          "nothing new (≤ 2 misses) — exit 1 otherwise")
@@ -171,15 +171,16 @@ def main():
     from repro.runtime import (EngineConfig, EngineRequest, PagedExecutor,
                                RAPEngine, RAPServer, ShardedExecutor)
 
-    if args.compile_cache_dir:
+    cache_dir = ""
+    if args.compile_cache:
         # enable BEFORE the first compile: JAX latches the cache-used
         # decision process-wide at first use (see enable_compile_cache)
         from repro.runtime.engine import enable_compile_cache
-        enable_compile_cache(args.compile_cache_dir)
+        cache_dir = enable_compile_cache()
 
     cfg = get_smoke_config(args.arch).replace(n_layers=args.layers)
     model = registry.build(cfg)
-    params = model.init(jax.random.key(args.seed))
+    params = jax.jit(model.init)(jax.random.key(args.seed))
     corpus = SyntheticCorpus(cfg.vocab_size, seed=args.seed)
     calib = {k: jax.numpy.asarray(v)
              for k, v in corpus.batch(2, 64, split="calib").items()}
@@ -241,7 +242,7 @@ def main():
             mode=mode, max_new_tokens=args.max_new, max_active=args.slots,
             max_len=max_total, budget_bytes=budget, decode_horizon=horizon,
             kv_dtype=kv_dtype, bucket_quant=args.bucket_quant,
-            compile_cache_dir=args.compile_cache_dir),
+            compile_cache=args.compile_cache),
             scheduler=args.scheduler, executor=executor)
         if not args.no_warmup:      # steady-state: compiles amortize away
             for _ in range(5):
@@ -571,7 +572,7 @@ def main():
                             # (default pow2: bounded compiled-executable
                             # set); rows gain cache_hits/cache_misses from
                             # the persistent XLA compilation cache
-                            # (--compile-cache-dir) and the document gains
+                            # (--compile-cache) and the document gains
                             # a "compile_cache" section;
                             # --assert-cache-replay hard-gates a warmed
                             # second invocation to near-zero recompiles.
@@ -628,20 +629,20 @@ def main():
             "shock_frac": args.shock_frac,
             "cancel_frac": args.cancel_frac,
             "bucket_quant": args.bucket_quant,
-            "compile_cache_dir": args.compile_cache_dir,
+            "compile_cache_dir": cache_dir,
         },
         "rows": rows,
         "interference": interference,
         "scenarios": scenarios,
     }
-    if args.compile_cache_dir:
+    if args.compile_cache:
         from repro.runtime.engine import _CACHE_EVENTS
-        doc["compile_cache"] = {"dir": args.compile_cache_dir,
+        doc["compile_cache"] = {"dir": cache_dir,
                                 "hits": _CACHE_EVENTS["hits"],
                                 "misses": _CACHE_EVENTS["misses"]}
         print(f"[bench] compile cache: {doc['compile_cache']['hits']} disk "
               f"hits, {doc['compile_cache']['misses']} misses "
-              f"({args.compile_cache_dir})")
+              f"({cache_dir})")
     bench_out = os.path.join(args.out, "BENCH_engine.json")
     with open(bench_out, "w") as f:
         json.dump(doc, f, indent=1)
@@ -756,15 +757,15 @@ def main():
                 f"generalizes")
 
     # Cache-replay gate (DESIGN.md §9, opt-in) — CI runs the bench twice
-    # against the same --compile-cache-dir; the second invocation passes
+    # with --compile-cache; the second invocation passes
     # --assert-cache-replay and must load its executables from disk: same
     # config ⇒ same traces ⇒ every compile should be a cache hit. A small
     # miss slack absorbs executables whose keys legitimately vary across
     # processes (e.g. autotuning); near-zero is the contract.
     if args.assert_cache_replay:
-        if not args.compile_cache_dir:
+        if not args.compile_cache:
             raise SystemExit("[bench] FAIL: --assert-cache-replay needs "
-                             "--compile-cache-dir")
+                             "--compile-cache")
         from repro.runtime.engine import _CACHE_EVENTS
         hits, misses = _CACHE_EVENTS["hits"], _CACHE_EVENTS["misses"]
         if hits <= 0 or misses > 2:
@@ -772,7 +773,7 @@ def main():
                 f"[bench] FAIL: warmed replay did not reuse the persistent "
                 f"compile cache ({hits} hits, {misses} misses; need > 0 "
                 f"hits and ≤ 2 misses) — a second identical invocation "
-                f"must load executables from {args.compile_cache_dir}, "
+                f"must load executables from {cache_dir}, "
                 f"not recompile the serving set")
         print(f"[bench] cache replay gate passed: {hits} hits, "
               f"{misses} misses")

@@ -77,13 +77,21 @@ def compact_params(params: dict, cfg, mask: np.ndarray):
     Masking became structure: the compacted stacks hold only retained
     blocks, so callers run forward/decode with ``layout`` and no gates
     (or all-ones gates over the compacted layout).
+
+    A kind whose gather keeps every row in order (the full mask keeps all
+    of them) returns the dense stack itself, not a copy: a full-depth
+    bucket must not hold a second set of weights beside ``params``.
     """
     layout, gather = compact_layout(cfg, mask)
     new_stacks = {}
     for kind, idxs in gather.items():
+        stack = params["stacks"][kind]
+        if list(idxs) == list(range(jax.tree.leaves(stack)[0].shape[0])):
+            new_stacks[kind] = stack
+            continue
         idx = jnp.asarray(idxs, jnp.int32)
         new_stacks[kind] = jax.tree.map(lambda x: jnp.take(x, idx, axis=0),
-                                        params["stacks"][kind])
+                                        stack)
     small = dict(params)
     small["stacks"] = new_stacks
     return small, layout

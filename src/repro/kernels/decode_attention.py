@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()      # pallas API rename (jax<=0.4.x)
-
 NEG_INF = -2.0e38
 _LANES = 128
 
@@ -109,7 +105,7 @@ def decode_attention(q, k, v, valid, *, softcap: float = 0.0,
             pltpu.VMEM((G, _LANES), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rap_decode_attention",

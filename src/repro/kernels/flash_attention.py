@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()      # pallas API rename (jax<=0.4.x)
-
 NEG_INF = -2.0e38
 _LANES = 128
 
@@ -141,7 +137,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

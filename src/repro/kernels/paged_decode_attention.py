@@ -4,18 +4,28 @@ The dense decode kernel (``decode_attention.py``) streams a *contiguous*
 ``[B, S, K, D]`` cache — which forces the serving engine to materialize
 ``max_len × max_active`` slot caches and eat their internal fragmentation.
 This kernel's KV operands are instead a **global page pool**
-``[n_pages, page_tokens, K, D]`` shared by every in-flight request, plus an
+``[n_pages, K, page_tokens, D]`` shared by every in-flight request, plus an
 int32 per-request **page table** ``[B, max_pages]``: request ``b``'s tokens
 ``[ip·page_tokens, (ip+1)·page_tokens)`` live in physical page
 ``page_table[b, ip]`` (vLLM-block style, one level of indirection).
 
+Pages are **head-major**: one kv head's tokens of one page are a
+contiguous ``[page_tokens, D]`` tile, so the K/V block ``(1, 1,
+page_tokens, D)`` spans the array's two minor dims in full — the TPU
+tiling rule any kv-head count satisfies (a token-major ``[.., pt, K, D]``
+page would need a one-head block on the second-minor dim, which Mosaic
+refuses for K > 1).
+
 Grid ``(B, K_kv, max_pages)`` with the page dimension innermost
 (sequential). The page table and per-request lengths ride
 ``PrefetchScalarGridSpec`` scalar prefetch, so the K/V BlockSpec *index
-maps* chase the table — ``(page_table[b, ip], 0, g, 0)`` — and the pages
+maps* chase the table — ``(page_table[b, ip], g, 0, 0)`` — and the pages
 DMA straight from wherever they physically sit; no gather materializes a
-contiguous cache. The (m, l, acc) online-softmax scratch carry is identical
-to the dense kernel's split-KV reduction, so with
+contiguous cache. Quantized pools' per-page scales are NOT scalar
+prefetched (SMEM would then grow with the pool): each request's scale
+rows are gathered through its table outside the kernel and arrive as a
+small VMEM block. The (m, l, acc) online-softmax scratch carry is
+identical to the dense kernel's split-KV reduction, so with
 ``page_tokens == block_k`` and an in-order page table the two kernels
 execute the *same* f32 op sequence and agree **bitwise** (pinned in
 ``tests/test_kernels.py``).
@@ -37,10 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()      # pallas API rename (jax<=0.4.x)
 
 NEG_INF = -2.0e38
 _LANES = 128
@@ -88,28 +94,35 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
     ip = pl.program_id(2)
     n_ip = pl.num_programs(2)
     q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-    k = k_ref[0, :, 0].astype(jnp.float32)           # [page_tokens, D]
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)              # [page_tokens, D]
+    v = v_ref[0, 0].astype(jnp.float32)
     _flash_step(b, ip, n_ip, q, k, v, len_ref, o_ref, m_sc, l_sc, acc_sc,
                 scale=scale, softcap=softcap, page_tokens=page_tokens)
 
 
-def _kernel_quant(pt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
+def _kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                   o_ref, m_sc, l_sc, acc_sc, *, scale: float, softcap: float,
                   page_tokens: int):
-    """Fused-dequant variant: pages arrive int8/fp8; per-(page, kv-head)
-    scales ride the scalar-prefetch path next to the page table, so the
-    dequant is one scalar multiply per tile — ``q.astype(f32) * scale`` —
-    exactly mirroring ``models.attention.page_dequant``. The (m, l, acc)
-    scratch stays fp32 via the shared ``_flash_step``."""
+    """Fused-dequant variant: pages arrive int8/fp8 and each tile is
+    widened and multiplied by its (page, kv-head) scale —
+    ``q.astype(f32) * scale``, exactly mirroring
+    ``models.attention.page_dequant``. The scales arrive as one VMEM row
+    per (request, kv-head) holding the row's per-page scales in table
+    order; the one-hot lane sum that picks page ``ip``'s scale adds only
+    exact zeros, so the multiply sees the stored f32 value. The (m, l,
+    acc) scratch stays fp32 via the shared ``_flash_step``."""
     b = pl.program_id(0)
-    g = pl.program_id(1)
     ip = pl.program_id(2)
     n_ip = pl.num_programs(2)
-    page = pt_ref[b, ip]
+
+    def page_scale(ref):
+        row = ref[0, 0]                                # [1, max_pages]
+        hit = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) == ip
+        return jnp.sum(jnp.where(hit, row, 0.0), axis=1, keepdims=True)
+
     q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[page, g]
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[page, g]
+    k = k_ref[0, 0].astype(jnp.float32) * page_scale(ks_ref)
+    v = v_ref[0, 0].astype(jnp.float32) * page_scale(vs_ref)
     _flash_step(b, ip, n_ip, q, k, v, len_ref, o_ref, m_sc, l_sc, acc_sc,
                 scale=scale, softcap=softcap, page_tokens=page_tokens)
 
@@ -117,20 +130,23 @@ def _kernel_quant(pt_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            k_scales=None, v_scales=None,
                            softcap: float = 0.0, interpret: bool = False):
-    """q: [B,1,H,D]; k_pages/v_pages: [n_pages, page_tokens, K, D];
+    """q: [B,1,H,D]; k_pages/v_pages: [n_pages, K, page_tokens, D];
     page_table: int32 [B, max_pages]; lengths: int32 [B]. → [B,1,H,D].
 
-    Row ``b`` attends its first ``lengths[b]`` tokens, token ``t`` living at
-    ``(page_table[b, t // page_tokens], t % page_tokens)``. Unused table
-    entries must still be valid page ids (they are fetched, then masked).
+    Row ``b`` attends its first ``lengths[b]`` tokens, token ``t`` of kv
+    head ``g`` living at ``(page_table[b, t // page_tokens], g,
+    t % page_tokens)``. Unused table entries must still be valid page ids
+    (they are fetched, then masked).
 
     ``k_scales``/``v_scales`` (f32 ``[n_pages, K]``, both or neither)
-    switch on the fused-dequant path for int8/fp8 page pools: scales are
-    scalar-prefetched alongside the page table and each K/V tile is
-    multiplied by its page's per-head scale before the fp32 online softmax.
+    switch on the fused-dequant path for int8/fp8 page pools: each
+    request's scale rows are gathered through its page table (``[B, K,
+    max_pages]``, independent of the pool size) and each K/V tile is
+    multiplied by its page's per-head scale before the fp32 online
+    softmax.
     """
     B, _, H, D = q.shape
-    page_tokens, K = k_pages.shape[1], k_pages.shape[2]
+    K, page_tokens = k_pages.shape[1], k_pages.shape[2]
     max_pages = page_table.shape[1]
     assert H % K == 0
     G = H // K
@@ -142,32 +158,32 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     page_table = jnp.asarray(page_table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
 
-    # scalar-prefetch operands lead the positional args; BlockSpec index
-    # maps receive them after the grid ids, so the two layouts need their
-    # own lambdas (the quantized maps take the two trailing scale refs)
+    # scalar prefetch (page table + lengths) leads the positional args;
+    # BlockSpec index maps receive those refs after the grid ids
+    q_map = lambda b, g, ip, tab, ln: (b, g, 0, 0)
+    kv_map = lambda b, g, ip, tab, ln: (tab[b, ip], g, 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, 1, G, D), q_map),
+        pl.BlockSpec((1, 1, page_tokens, D), kv_map),
+        pl.BlockSpec((1, 1, page_tokens, D), kv_map),
+    ]
+    operands = [qg, k_pages, v_pages]
+    body = _kernel
     if quantized:
-        kernel = functools.partial(_kernel_quant, scale=1.0 / math.sqrt(D),
-                                   softcap=softcap, page_tokens=page_tokens)
-        num_prefetch = 4                 # page_table, lengths, ks, vs
-        q_map = lambda b, g, ip, tab, ln, ks, vs: (b, g, 0, 0)
-        kv_map = lambda b, g, ip, tab, ln, ks, vs: (tab[b, ip], 0, g, 0)
-        prefetch = (page_table, lengths, jnp.asarray(k_scales, jnp.float32),
-                    jnp.asarray(v_scales, jnp.float32))
-    else:
-        kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(D),
-                                   softcap=softcap, page_tokens=page_tokens)
-        num_prefetch = 2                 # page_table, lengths
-        q_map = lambda b, g, ip, tab, ln: (b, g, 0, 0)
-        kv_map = lambda b, g, ip, tab, ln: (tab[b, ip], 0, g, 0)
-        prefetch = (page_table, lengths)
+        body = _kernel_quant
+        # [B, K, 1, max_pages]: the trailing (1, max_pages) block spans
+        # the full dims, and the block index is constant along the page
+        # walk, so each (row, head) fetches its scales once
+        for s in (k_scales, v_scales):
+            rows = jnp.asarray(s, jnp.float32)[page_table]   # [B, maxp, K]
+            operands.append(jnp.transpose(rows, (0, 2, 1))[:, :, None, :])
+            in_specs.append(pl.BlockSpec((1, 1, 1, max_pages), q_map))
+    kernel = functools.partial(body, scale=1.0 / math.sqrt(D),
+                               softcap=softcap, page_tokens=page_tokens)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
+        num_scalar_prefetch=2,
         grid=(B, K, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), q_map),
-            pl.BlockSpec((1, page_tokens, 1, D), kv_map),
-            pl.BlockSpec((1, page_tokens, 1, D), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((G, _LANES), jnp.float32),
@@ -179,10 +195,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=("rap_paged_decode_attention_quant" if quantized
               else "rap_paged_decode_attention"),
-    )(*prefetch, qg, k_pages, v_pages)
+    )(page_table, lengths, *operands)
     return out.reshape(B, 1, H, D)

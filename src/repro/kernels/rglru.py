@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()      # pallas API rename (jax<=0.4.x)
-
 
 def _kernel(a_ref, b_ref, h_ref, carry_ref):
     it = pl.program_id(2)
@@ -73,7 +69,7 @@ def rglru(a, b, *, block_t: int = 256, block_w: int = 512,
                                lambda bb, iw, it: (bb, it, iw)),
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rap_rglru",

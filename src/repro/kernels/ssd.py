@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()      # pallas API rename (jax<=0.4.x)
-
 
 def _kernel(xh_ref, la_ref, b_ref, c_ref, y_ref, fin_ref, st_ref, *,
             block_q: int):
@@ -112,7 +108,7 @@ def ssd(xh, log_a, Bm, Cm, chunk: int = 256, *, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rap_ssd",

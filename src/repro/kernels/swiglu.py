@@ -17,10 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()      # pallas API rename (jax<=0.4.x)
-
 
 def _kernel(gate_ref, up_ref, o_ref, *, activation: str):
     g = gate_ref[...].astype(jnp.float32)
@@ -60,7 +56,7 @@ def fused_glu(h, activation: str = "swiglu", *, block_t: int = 256,
         ],
         out_specs=pl.BlockSpec((block_t, block_f), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], F), h.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="rap_fused_glu",

@@ -15,28 +15,30 @@ from typing import Optional, Tuple
 import numpy as np
 
 
+def _make_mesh(shape, axes, devices):
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     import jax
 
-    from repro.compat import make_mesh
-
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     devices=jax.devices()[: int(np.prod(shape))])
+    return _make_mesh(shape, axes, jax.devices()[: int(np.prod(shape))])
 
 
 def make_host_mesh(shape: Tuple[int, ...] = None, axes=None):
     """Small mesh over whatever devices exist (tests / local runs)."""
     import jax
 
-    from repro.compat import make_mesh
-
     n = len(jax.devices())
     if shape is None:
         shape, axes = (n, 1), ("data", "model")
-    return make_mesh(shape, axes,
-                     devices=jax.devices()[: int(np.prod(shape))])
+    return _make_mesh(shape, axes, jax.devices()[: int(np.prod(shape))])
 
 
 def make_serve_mesh(n_slots: Optional[int] = None, *, model: int = 1):

@@ -21,6 +21,22 @@ from __future__ import annotations
 
 import argparse
 
+# Device memory the launcher leaves outside the engine's budget when it
+# caps the default budget at the device's capacity: XLA's workspace for
+# the serving executables (prefill activations and logits, decode-loop
+# temporaries, the int8/fp8 page-write staging) lives outside both the
+# weights and the KV pool the budget accounts for.
+DEVICE_HEADROOM_BYTES = 1 << 30
+
+
+def device_budget_cap():
+    """``bytes_limit`` of the first device less
+    :data:`DEVICE_HEADROOM_BYTES`, or ``None`` where the backend reports
+    no memory limit (CPU)."""
+    import jax
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return None if not limit else float(limit - DEVICE_HEADROOM_BYTES)
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -113,12 +129,13 @@ def main():
                          "a bounded executable set; 'pow2' bounds it at "
                          "ceil(log2 L)+1 families. The paged executor "
                          "floors 'none' at 'layer'")
-    ap.add_argument("--compile-cache-dir", default="",
-                    help="enable JAX's persistent compilation cache rooted "
-                         "here: a second serve of the same config re-traces "
-                         "but loads XLA binaries from disk instead of "
-                         "recompiling (near-zero warm-start compiles; "
-                         "DESIGN.md §9)")
+    ap.add_argument("--compile-cache", action="store_true",
+                    help="enable JAX's persistent compilation cache "
+                         "(JAX_COMPILATION_CACHE_DIR when set, else "
+                         ".jax_cache/ in the checkout): a second serve of "
+                         "the same config re-traces but loads XLA binaries "
+                         "from disk instead of recompiling (near-zero "
+                         "warm-start compiles; DESIGN.md §9)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     if args.chunked_prefill and args.max_prefill_tokens <= 0:
@@ -145,7 +162,9 @@ def main():
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = registry.build(cfg)
-    params = model.init(jax.random.key(args.seed))
+    # under jit the layer stacks are written straight into their outputs:
+    # one copy of the weights at peak, not per-layer trees plus the stack
+    params = jax.jit(model.init)(jax.random.key(args.seed))
     corpus = SyntheticCorpus(cfg.vocab_size, seed=args.seed)
     calib = {k: jax.numpy.asarray(v)
              for k, v in corpus.batch(2, 64, split="calib").items()}
@@ -202,6 +221,12 @@ def main():
     max_b = max(r.batch for r in reqs)
     budget = (mm.param_bytes(full)
               + args.pool_requests * mm.state_bytes(full, max_b, max_total))
+    cap = device_budget_cap()
+    if cap is not None and budget > cap:
+        print(f"budget {budget / 1e9:.2f}GB capped at the device's "
+              f"{cap / 1e9:.2f}GB (bytes_limit less "
+              f"{DEVICE_HEADROOM_BYTES / 2**30:.0f}GiB headroom)")
+        budget = cap
     kv_dtype = None if args.kv_dtype == "model" else args.kv_dtype
     if kv_dtype == "auto":
         # precision as a policy action, resolved ONCE at startup (one pool
@@ -252,7 +277,7 @@ def main():
         max_prefill_tokens=args.max_prefill_tokens,
         preemption_enabled=args.enable_preemption,
         bucket_quant=args.bucket_quant,
-        compile_cache_dir=args.compile_cache_dir),
+        compile_cache=args.compile_cache),
         scheduler=args.scheduler, executor=executor)
     ereqs = []
     for i, r in enumerate(reqs):
@@ -306,11 +331,12 @@ def main():
           f"{rep.decode_iters} decode iters, "
           f"mean queue {rep.mean_queue_delay_s*1e3:.0f}ms, "
           f"fit-rate {rep.budget_fit_rate:.2f}")
-    if args.compile_cache_dir:
+    if args.compile_cache:
+        from repro.runtime.engine import compile_cache_dir
         print(f"compile cache: {rep.compile_events} traces, "
               f"{rep.compile_cache_hits} disk hits, "
               f"{rep.compile_cache_misses} misses "
-              f"({args.compile_cache_dir})")
+              f"({compile_cache_dir()})")
     if rep.preempted_count:
         print(f"preemption: {rep.preempted_count} preempted, "
               f"{rep.spilled_mb:.2f}MB spilled, resume p50/p99 "

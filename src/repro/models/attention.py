@@ -14,6 +14,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.models import layers
 
@@ -44,11 +45,25 @@ def init_attn_params(rng, cfg) -> dict:
     return p
 
 
+def _row_major(w):
+    """Pin ``w`` to the row-major device layout its stacked parameter is
+    stored in. At decode's few-token batches the TPU compiler prefers the
+    q/k/v projection weights transposed, and inside a layer scan that
+    preference reaches the whole stacked parameter: it copies every
+    layer's wq/wk/wv up front (3 GB at llama2-7b, more than one chip has
+    beside the weights)."""
+    return with_layout_constraint(
+        w, Layout(major_to_minor=tuple(range(w.ndim))))
+
+
 def _project_qkv(params, cfg, x):
     """x: [B, S, D] → q [B,S,H,Dh], k/v [B,S,K,Dh]."""
-    q = jnp.einsum("bsd,de->bse", x, params["wq"].astype(x.dtype))
-    k = jnp.einsum("bsd,de->bse", x, params["wk"].astype(x.dtype))
-    v = jnp.einsum("bsd,de->bse", x, params["wv"].astype(x.dtype))
+    q = jnp.einsum("bsd,de->bse", x,
+                   _row_major(params["wq"]).astype(x.dtype))
+    k = jnp.einsum("bsd,de->bse", x,
+                   _row_major(params["wk"]).astype(x.dtype))
+    v = jnp.einsum("bsd,de->bse", x,
+                   _row_major(params["wv"]).astype(x.dtype))
     if cfg.qkv_bias:
         q = q + params["bq"].astype(x.dtype)
         k = k + params["bk"].astype(x.dtype)
@@ -195,8 +210,8 @@ def page_qmax(dtype) -> float:
 
 
 def page_quant(xf, dtype, scale_floor=None):
-    """Quantize whole pages ``[..., page_tokens, K, Dh]`` (f32) into
-    ``dtype`` with ONE symmetric scale per (page, kv-head): returns
+    """Quantize whole head-major pages ``[..., K, page_tokens, Dh]`` (f32)
+    into ``dtype`` with ONE symmetric scale per (page, kv-head): returns
     ``(q, scales[..., K])``.
 
     ``scale_floor`` (same shape as the scales) makes the scale monotone
@@ -204,7 +219,7 @@ def page_quant(xf, dtype, scale_floor=None):
     amax, the scale is unchanged and requantizing the page's existing
     tokens reproduces their stored codes exactly (``round(s·q/s) == q``),
     so repeated appends drift only when the scale actually grows."""
-    amax = jnp.max(jnp.abs(xf), axis=(-3, -1))            # [..., K]
+    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))            # [..., K]
     qmax = page_qmax(dtype)
     scale = amax / qmax
     if scale_floor is not None:
@@ -212,7 +227,7 @@ def page_quant(xf, dtype, scale_floor=None):
     # epsilon as a FLOOR, not an addend: adding it after the max would
     # grow a stable page's scale every requantization
     scale = jnp.maximum(scale, 1e-8)
-    y = xf / scale[..., None, :, None]
+    y = xf / scale[..., None, None]
     if jnp.dtype(dtype) == jnp.int8:
         q = jnp.clip(jnp.round(y), -qmax, qmax).astype(jnp.int8)
     else:
@@ -221,10 +236,24 @@ def page_quant(xf, dtype, scale_floor=None):
 
 
 def page_dequant(q, scales):
-    """Dequantize pages ``[..., page_tokens, K, Dh]`` with per-(page, head)
-    scales ``[..., K]`` to f32 — the reference the fused kernel is pinned
-    bitwise against (``q.astype(f32) * scale`` per element, nothing else)."""
-    return q.astype(jnp.float32) * scales[..., None, :, None]
+    """Dequantize head-major pages ``[..., K, page_tokens, Dh]`` with
+    per-(page, head) scales ``[..., K]`` to f32 — the reference the fused
+    kernel is pinned bitwise against (``q.astype(f32) * scale`` per
+    element, nothing else)."""
+    return q.astype(jnp.float32) * scales[..., None, None]
+
+
+def gather_pages(kv: dict, name: str, page_table):
+    """Contiguous per-row view ``[B, max_pages * page_tokens, K, Dh]`` of
+    one pool leaf (``"k"`` or ``"v"``) through ``page_table`` [B,
+    max_pages] — the XLA gather fallback's read of head-major pages.
+    Quantized pools come back dequantized to f32 (``page_dequant``)."""
+    pages = kv[name][page_table]                 # [B, maxp, K, pt, Dh]
+    scales = kv.get(name + "s")
+    if scales is not None:
+        pages = page_dequant(pages, scales[page_table])
+    B, maxp, K, pt, Dh = pages.shape
+    return jnp.swapaxes(pages, 2, 3).reshape(B, maxp * pt, K, Dh)
 
 
 def init_kv_cache(cfg, batch: int, max_len: int, n_layers: int, dtype=None):
@@ -264,8 +293,8 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
                            impl: str = "xla") -> Tuple[jnp.ndarray, dict]:
     """One-token decode against a *paged* KV pool (one layer's slice).
 
-    x: [B,1,D]; kv: {"k","v"} page pools [n_pages, page_tokens, K, Dh]
-    shared by every in-flight request; page_table: int32 [B, max_pages]
+    x: [B,1,D]; kv: {"k","v"} head-major page pools [n_pages, K,
+    page_tokens, Dh] shared by every in-flight request; page_table: int32 [B, max_pages]
     mapping row b's token t to page ``page_table[b, t // page_tokens]``;
     pos: int32 [B] per-row write positions. Returns (out [B,1,D], kv').
 
@@ -282,14 +311,14 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
     existing codes rescale by ``old/new`` (exactly 1.0 while the scale
     is stable, so they round-trip bitwise), the token quantizes into its
     slot, and stale slots past the write frontier stay zero. The read
-    path dequantizes — fused into the Pallas kernel via scalar-prefetched
+    path dequantizes — fused into the Pallas kernel via per-row gathered
     scales, or mirrored exactly in the XLA gather (``q.astype(f32) *
     scale``) so both paths see identical f32 values.
     """
     B = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     page_table = jnp.asarray(page_table, jnp.int32)
-    page_tokens = kv["k"].shape[1]
+    page_tokens = kv["k"].shape[2]
     quantized = "ks" in kv
     q, k, v = _project_qkv(params, cfg, x)
     positions = jnp.broadcast_to(pos.reshape(-1, 1), (B, 1))
@@ -300,6 +329,8 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
     rows = jnp.arange(B)
     page_ids = page_table[rows, pos // page_tokens]
     offs = pos % page_tokens
+    slot = jnp.arange(page_tokens)[None, None, :, None]       # [1, 1, pt, 1]
+    off_b = offs[:, None, None, None]                         # [B, 1, 1, 1]
     kv = dict(kv)
     if quantized:
         # code-space append (rows own disjoint pages; only padded rows
@@ -310,8 +341,6 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
         # old/new, which is exactly 1.0 while the scale is stable: the
         # common-case append rewrites the page bitwise-unchanged plus the
         # one inserted slot, at a fraction of a dequant→requant pass.
-        slot = jnp.arange(page_tokens)[None, :, None, None]   # [1, pt, 1, 1]
-        off_b = offs[:, None, None, None]                     # [B, 1, 1, 1]
         fresh = (offs == 0)[:, None]                          # [B, 1]
         for pk, sk, new in (("k", "ks", k), ("v", "vs", v)):
             qmax = page_qmax(kv[pk].dtype)
@@ -324,20 +353,24 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
             new_s = jnp.maximum(jnp.maximum(
                 jnp.max(jnp.abs(tok), axis=-1) / qmax, floor), 1e-8)
             r = jnp.where(fresh, 0.0, old_s / new_s)          # [B, K] <= 1
-            pg = kv[pk][page_ids].astype(jnp.float32) * r[:, None, :, None]
+            pg = kv[pk][page_ids].astype(jnp.float32) * r[:, :, None, None]
             tok_q = tok / new_s[..., None]
             if int_codes:
                 pg, tok_q = jnp.round(pg), jnp.round(tok_q)
-            pg = jnp.where(slot == off_b, tok_q[:, None], pg)
+            pg = jnp.where(slot == off_b, tok_q[:, :, None], pg)
             pg = jnp.where(slot <= off_b, pg, 0.0)  # stale slots → 0
             kv[pk] = kv[pk].at[page_ids].set(
                 jnp.clip(pg, -qmax, qmax).astype(kv[pk].dtype))
             kv[sk] = kv[sk].at[page_ids].set(new_s)
     else:
-        kv["k"] = kv["k"].at[page_ids, offs].set(
-            k[:, 0].astype(kv["k"].dtype))
-        kv["v"] = kv["v"].at[page_ids, offs].set(
-            v[:, 0].astype(kv["v"].dtype))
+        # whole-page rewrite, as in the quantized append: a scatter of
+        # single token slots (indices on the page and token dims) makes
+        # the TPU compiler re-lay-out, i.e. copy, the whole pool
+        for pk, new in (("k", k), ("v", v)):
+            pg = kv[pk][page_ids]                             # [B, K, pt, Dh]
+            pg = jnp.where(slot == off_b,
+                           new[:, 0, :, None].astype(pg.dtype), pg)
+            kv[pk] = kv[pk].at[page_ids].set(pg)
     lengths = pos + 1
     if impl == "pallas":
         from repro.kernels import ops as kops
@@ -348,15 +381,9 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
     else:
         # gather fallback: page_table indexes the pool back into a
         # contiguous per-row view [B, max_pages*page_tokens, K, Dh]
-        S = page_table.shape[1] * page_tokens
-        if quantized:
-            ck = page_dequant(kv["k"][page_table], kv["ks"][page_table])
-            cv = page_dequant(kv["v"][page_table], kv["vs"][page_table])
-            ck = ck.reshape(B, S, *ck.shape[3:])
-            cv = cv.reshape(B, S, *cv.shape[3:])
-        else:
-            ck = kv["k"][page_table].reshape(B, S, *kv["k"].shape[2:])
-            cv = kv["v"][page_table].reshape(B, S, *kv["v"].shape[2:])
+        ck = gather_pages(kv, "k", page_table)
+        cv = gather_pages(kv, "v", page_table)
+        S = ck.shape[1]
         valid = jnp.arange(S)[None, :] < lengths[:, None]      # [B, S]
         out = _sdpa(cfg, q, ck.astype(q.dtype), cv.astype(q.dtype),
                     valid[:, None, None, :])
@@ -407,7 +434,8 @@ def paged_chunk_attention(params, cfg, x, kv: dict, page_table, start, *,
     """Paged sibling of :func:`chunk_attention`: prefill C prompt tokens
     straight into granted pages.
 
-    x: [B, C, D]; kv: {"k","v"} page pools [n_pages, page_tokens, K, Dh];
+    x: [B, C, D]; kv: {"k","v"} head-major page pools [n_pages, K,
+    page_tokens, Dh];
     page_table: int32 [B, max_pages]; start: int32 scalar — the chunk's
     first absolute position (every row of a chunked-prefill request sits
     at the same offset). Tokens whose position falls past the table width
@@ -423,7 +451,7 @@ def paged_chunk_attention(params, cfg, x, kv: dict, page_table, start, *,
     B, C = x.shape[:2]
     start = jnp.asarray(start, jnp.int32)
     page_table = jnp.asarray(page_table, jnp.int32)
-    page_tokens = kv["k"].shape[1]
+    page_tokens = kv["k"].shape[2]
     max_pages = page_table.shape[1]
     quantized = "ks" in kv
     q, k, v = _project_qkv(params, cfg, x)
@@ -454,8 +482,7 @@ def paged_chunk_attention(params, cfg, x, kv: dict, page_table, start, *,
         live = (kpos < frontier)[None, :, None, None]       # [1, S, 1, 1]
         fresh_col = (col_ids * page_tokens >= start)[None, :, None]
         for pk, sk, new in (("k", "ks", k), ("v", "vs", v)):
-            view = page_dequant(kv[pk][page_table], kv[sk][page_table])
-            view = view.reshape(B, S, *view.shape[3:])      # [B, S, K, Dh]
+            view = gather_pages(kv, pk, page_table)         # [B, S, K, Dh]
             # pad by C so an over-the-table chunk spills off the end
             # instead of letting dynamic_update_slice clamp onto live data
             view = jnp.concatenate(
@@ -463,24 +490,20 @@ def paged_chunk_attention(params, cfg, x, kv: dict, page_table, start, *,
             view = jax.lax.dynamic_update_slice(
                 view, new.astype(jnp.float32), (0, start, 0, 0))[:, :S]
             view = jnp.where(live, view, 0.0)               # stale slots → 0
-            pages = view.reshape(B, max_pages, page_tokens, *view.shape[2:])
+            pages = jnp.swapaxes(
+                view.reshape(B, max_pages, page_tokens, *view.shape[2:]),
+                2, 3)                                       # head-major
             floor = jnp.where(fresh_col, 0.0, kv[sk][page_table])
             qp, sp = page_quant(pages, kv[pk].dtype, scale_floor=floor)
             kv[pk] = kv[pk].at[write_ids].set(qp)
             kv[sk] = kv[sk].at[write_ids].set(sp)
     else:
-        kv["k"] = kv["k"].at[page_ids, offs].set(k.astype(kv["k"].dtype))
-        kv["v"] = kv["v"].at[page_ids, offs].set(v.astype(kv["v"].dtype))
+        kv["k"] = kv["k"].at[page_ids, :, offs].set(k.astype(kv["k"].dtype))
+        kv["v"] = kv["v"].at[page_ids, :, offs].set(v.astype(kv["v"].dtype))
     # gather fallback view [B, max_pages*page_tokens, K, Dh] + causal mask
-    S = max_pages * page_tokens
-    if quantized:
-        ck = page_dequant(kv["k"][page_table], kv["ks"][page_table])
-        cv = page_dequant(kv["v"][page_table], kv["vs"][page_table])
-        ck = ck.reshape(B, S, *ck.shape[3:])
-        cv = cv.reshape(B, S, *cv.shape[3:])
-    else:
-        ck = kv["k"][page_table].reshape(B, S, *kv["k"].shape[2:])
-        cv = kv["v"][page_table].reshape(B, S, *kv["v"].shape[2:])
+    ck = gather_pages(kv, "k", page_table)
+    cv = gather_pages(kv, "v", page_table)
+    S = ck.shape[1]
     mask = _causal_mask(C, S, 0, q_offset=start)
     out = _sdpa(cfg, q, ck.astype(q.dtype), cv.astype(q.dtype), mask)
     y = jnp.einsum("bsq,qm->bsm", out.reshape(B, C, -1),

@@ -74,10 +74,14 @@ _FFN_INIT = {
 
 
 def _stack_init(rng, n: int, init_fn, cfg):
+    """``n`` layers' params stacked on a leading axis, layer ``i`` drawn
+    from the ``i``-th split of ``rng``. One vmapped draw builds each
+    stacked leaf directly (no per-layer trees to stack): a jitted
+    ``init`` writes every weight once, with one op per leaf rather than
+    one per layer, which keeps its compile short at full depth."""
     keys = jax.random.split(rng, n)
-    trees = [dict(norm=layers.init_norm(cfg), **init_fn(keys[i], cfg))
-             for i in range(n)]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+    return jax.vmap(
+        lambda k: dict(norm=layers.init_norm(cfg), **init_fn(k, cfg)))(keys)
 
 
 def init_params(rng, cfg) -> dict:
@@ -622,7 +626,7 @@ def paged_prefill_chunk(params, cfg, pools: dict, page_table, tokens, start,
     """Paged sibling of :func:`prefill_chunk`: one prompt chunk appended
     straight into granted pages.
 
-    pools: {"k","v"} [L, n_pages, page_tokens, K, Dh] — quantized pools
+    pools: {"k","v"} [L, n_pages, K, page_tokens, Dh] — quantized pools
     add per-page scale leaves {"ks","vs"} [L, n_pages, K]; page_table:
     int32 [B, max_pages]; tokens [B, C] at absolute offset ``start``. The
     pool arrays ride the layer scan's carry (donated, in-place) exactly
@@ -844,7 +848,8 @@ def paged_decode_step(params, cfg, pools: dict, page_table, pos, tokens, *,
                       layout=None) -> Tuple[jnp.ndarray, dict]:
     """One autoregressive step against a *paged* KV pool.
 
-    pools: {"k","v"} global page arrays [L, n_pages, page_tokens, K, Dh]
+    pools: {"k","v"} global head-major page arrays [L, n_pages, K,
+    page_tokens, Dh]
     (one pool slice per attention layer, stacked — a page id is valid at
     every layer; quantized pools add {"ks","vs"} [L, n_pages, K] scales);
     page_table: int32 [B, max_pages]; pos: int32 [B] per-row

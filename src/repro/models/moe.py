@@ -156,7 +156,6 @@ def moe_ffn_ep(params, cfg, x, pol):
     FSDP composition: when weights carry an extra "data" shard, the body
     all-gathers them before use (explicit ZeRO-3 gather, visible in HLO).
     """
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.sharding import _add_fsdp, _param_rule
@@ -212,9 +211,9 @@ def moe_ffn_ep(params, cfg, x, pol):
         out = jax.lax.psum(out, "model")
         return out.reshape(x_loc.shape)
 
-    ep_call = shard_map(body, mesh=mesh,
-                        in_specs=(x_spec, wi_spec, wo_spec, P()),
-                        out_specs=x_spec, check_vma=False)
+    ep_call = jax.shard_map(body, mesh=mesh,
+                            in_specs=(x_spec, wi_spec, wo_spec, P()),
+                            out_specs=x_spec, check_vma=False)
 
     # Outer sequence chunking: the shard_map boundary materializes x (and
     # its f32 cotangent) at full sequence length per data shard; mapping

@@ -58,6 +58,7 @@ are reported back to the policy via ``feedback()``.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -75,7 +76,7 @@ from repro.runtime.scheduler import (Scheduler, VictimCandidate,
                                      make_scheduler)
 
 __all__ = ["EngineConfig", "EngineRequest", "RequestResult", "EngineReport",
-           "RAPEngine", "enable_compile_cache"]
+           "RAPEngine", "compile_cache_dir", "enable_compile_cache"]
 
 _MIGRATION_HINT = (
     "RAPEngine's constructor changed with the serving-API split: it now "
@@ -122,8 +123,26 @@ def _on_jax_monitoring_event(event: str, **kw) -> None:
         _CACHE_EVENTS["misses"] += 1
 
 
-def enable_compile_cache(cache_dir: str) -> None:
-    """Root JAX's persistent compilation cache at ``cache_dir``.
+# the fixed in-checkout cache location: ``.jax_cache/`` at the repository
+# root (listed in .gitignore). A fixed path matters — the directory is part
+# of what a later process must find again, so it is never a temp, pid or
+# time-based name.
+CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """Where the persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set, otherwise
+    :data:`CHECKOUT_COMPILE_CACHE`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        CHECKOUT_COMPILE_CACHE
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`compile_cache_dir` and return that directory.
 
     A second serve of the same config (same process or a fresh one)
     re-traces its executables but deserializes the XLA binaries from disk
@@ -133,7 +152,8 @@ def enable_compile_cache(cache_dir: str) -> None:
     populate the cache.
     """
     import jax
-    cache_dir = str(cache_dir)
+    from jax._src import compilation_cache as _cc
+    cache_dir = compile_cache_dir()
     changed = _CACHE_LISTENER.get("dir") != cache_dir
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -141,17 +161,14 @@ def enable_compile_cache(cache_dir: str) -> None:
     if changed:
         # JAX latches the cache-used decision at the process's FIRST
         # compile: a process that already compiled with caching off (any
-        # engine built without compile_cache_dir) must reset the latch or
-        # the new dir is silently ignored
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except (ImportError, AttributeError):   # private API moved
-            pass
+        # engine built without compile_cache) must reset the latch or the
+        # directory is silently ignored
+        _cc.reset_cache()
         _CACHE_LISTENER["dir"] = cache_dir
     if not _CACHE_LISTENER["registered"]:
         jax.monitoring.register_event_listener(_on_jax_monitoring_event)
         _CACHE_LISTENER["registered"] = True
+    return cache_dir
 
 
 # ------------------------------------------------------------------- config
@@ -234,11 +251,12 @@ class EngineConfig:
     # their prefill executables and — when they were the signature's last
     # group — the resident compacted param stack.
     max_structural_groups: int = 0
-    # Non-empty: enable JAX's persistent compilation cache rooted here
-    # (enable_compile_cache), so a second serve of the same config skips
-    # XLA compilation. Per-run activity is reported as
+    # Enable JAX's persistent compilation cache (enable_compile_cache:
+    # JAX_COMPILATION_CACHE_DIR when set, else .jax_cache/ in the
+    # checkout), so a second serve of the same config skips XLA
+    # compilation. Per-run activity is reported as
     # EngineReport.compile_cache_hits / compile_cache_misses.
-    compile_cache_dir: str = ""
+    compile_cache: bool = False
 
     def __post_init__(self):
         if self.mode not in ("masked", "structural"):
@@ -367,7 +385,7 @@ class EngineReport:
     compile_events: int
     pool: Dict[str, float]
     # persistent-compile-cache activity during the run (zeros unless
-    # EngineConfig.compile_cache_dir enabled the cache): a hit means a
+    # EngineConfig.compile_cache enabled the cache): a hit means a
     # traced executable was deserialized from disk instead of recompiled,
     # so a warmed replay shows compile_events ≈ compile_cache_hits and
     # near-zero misses
@@ -499,8 +517,8 @@ class RAPEngine:
         # from its actual cache sizes
         self.cfg = dataclasses.replace(cfg if cfg is not None
                                        else EngineConfig())
-        if self.cfg.compile_cache_dir:
-            enable_compile_cache(self.cfg.compile_cache_dir)
+        if self.cfg.compile_cache:
+            enable_compile_cache()
         self.mm = policy.mm
         self.scheduler = make_scheduler(scheduler)
         self.executor = executor if executor is not None else LocalExecutor(

@@ -1320,8 +1320,10 @@ class PagedExecutor(ModelExecutor):
                     gates={"mixer": gm, "ffn": gf}, layout=layout,
                     kv_dtype=cache_dtype)
                 kp, vp = pools["k"], pools["v"]
-                k = cache["attn"]["k"].reshape(Lp, b, npg, pt, *kp.shape[3:])
-                v = cache["attn"]["v"].reshape(Lp, b, npg, pt, *vp.shape[3:])
+                # [Lp, b, S, K, D] → head-major pages [Lp, b, npg, K, pt, D]
+                K, D = kp.shape[2], kp.shape[4]
+                k, v = (jnp.swapaxes(cache["attn"][n].reshape(
+                    Lp, b, npg, pt, K, D), 3, 4) for n in ("k", "v"))
                 pools = dict(pools)
                 if quantized:
                     qk, sk = attention.page_quant(

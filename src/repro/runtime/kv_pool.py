@@ -174,7 +174,7 @@ class SpilledAllocation:
     requested_bytes: float    # byte-kind ledger charge
     in_use_bytes: float
     in_use_per_token: float
-    k_host: object = None     # [L, held_pages, pt, K, D] page contents
+    k_host: object = None     # [L, held_pages, K, pt, D] page contents
     v_host: object = None
     k_scales_host: object = None   # [L, held_pages, K] f32 (quantized only)
     v_scales_host: object = None
@@ -204,7 +204,7 @@ class KVPool:
         self.spilled_bytes_total = 0.0   # cumulative device bytes spilled
         self._next_overflow_page = self.n_pages  # ids for overcommitted pages
         self._committed_extra = 0   # Σ token allocs (committed − held) pages
-        # physical page arrays (allocate_physical): [L, n_pages+1, pt, K, D]
+        # physical page arrays (allocate_physical): [L, n_pages+1, K, pt, D]
         self.k_pages = None
         self.v_pages = None
         # quantized pools: canonical dtype name + per-page scales
@@ -224,7 +224,10 @@ class KVPool:
                           head_dim: int, dtype, kv_dtype=None) -> None:
         """Materialize the page pools: one K and one V array per attention
         layer (stacked on a leading layer axis), sized once at capacity plus
-        one scratch page. Requires ``tokens_per_page``.
+        one scratch page. Requires ``tokens_per_page``. Pages are
+        head-major, ``[n_layers, n_pages+1, K, tokens_per_page, D]``: one
+        kv head's tokens of a page form the contiguous tile the paged
+        decode kernel fetches.
 
         ``kv_dtype`` selects the storage precision: ``None`` keeps ``dtype``
         as-is; ``"fp32"``/``"bf16"`` override the width; ``"int8"``/``"fp8"``
@@ -240,8 +243,8 @@ class KVPool:
         name, store_dtype, quantized, _ = resolve_kv_dtype(kv_dtype)
         self.kv_dtype = name
         phys = store_dtype if store_dtype is not None else dtype
-        shape = (n_layers, self.n_pages + 1, self.tokens_per_page,
-                 n_kv_heads, head_dim)
+        shape = (n_layers, self.n_pages + 1, n_kv_heads,
+                 self.tokens_per_page, head_dim)
         self.k_pages = jnp.zeros(shape, phys)
         self.v_pages = jnp.zeros(shape, phys)
         if quantized:

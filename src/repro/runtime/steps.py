@@ -77,7 +77,6 @@ def make_compressed_train_step(model, opt_cfg: adamw.AdamWConfig, mesh, *,
 
     (params, opt_state, residuals, batch) → (params', opt', residuals', m).
     """
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -95,10 +94,8 @@ def make_compressed_train_step(model, opt_cfg: adamw.AdamWConfig, mesh, *,
                    for k, v in {**aux, **om}.items()}
         return params, opt_state, residuals, metrics
 
-    rep = jax.tree.map(lambda _: P(), jax.eval_shape(lambda: 0))
-    del rep
     param_spec = P()          # replicated params (DP-only variant)
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh,
         in_specs=(param_spec, param_spec, param_spec, batch_pspecs_tree),
         out_specs=(param_spec, param_spec, param_spec, P()),

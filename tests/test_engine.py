@@ -1005,7 +1005,7 @@ def test_kv_pool_spill_restore_roundtrip_bitwise():
     rows = pool.row_pages("a")
     rng = np.random.default_rng(0)
     ids = [p for row in rows for p in row]
-    k_ref = rng.integers(-127, 127, (layers, len(ids), pt, K, D),
+    k_ref = rng.integers(-127, 127, (layers, len(ids), K, pt, D),
                          dtype=np.int8)
     s_ref = rng.uniform(0.1, 2.0, (layers, len(ids), K)).astype(np.float32)
     idx = jnp.asarray(np.asarray(ids, np.int32))

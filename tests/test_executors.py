@@ -577,7 +577,7 @@ STRUCT_PARAMS = ["local", "paged"]
 
 def _struct_engine(model, params, policy, kind, *, budget, max_new, slots=4,
                    max_len=32, horizon=8, kv_dtype=None, bucket_quant="none",
-                   max_groups=0, cache_dir=""):
+                   max_groups=0, compile_cache=False):
     ex = None
     if kind == "paged":
         ex = PagedExecutor(model, params, mode="structural", max_active=slots,
@@ -587,7 +587,7 @@ def _struct_engine(model, params, policy, kind, *, budget, max_new, slots=4,
         max_len=max_len, budget_bytes=budget, tokens_per_page=8,
         kv_dtype=kv_dtype, decode_horizon=horizon,
         bucket_quant=bucket_quant, max_structural_groups=max_groups,
-        compile_cache_dir=cache_dir), executor=ex)
+        compile_cache=compile_cache), executor=ex)
 
 
 def _drop_layer(cfg, *layers):
@@ -840,8 +840,8 @@ def test_invalidation_unified(tiny_model):
         assert s["resident_param_stacks"] == 0
 
 
-def test_persistent_compile_cache_hits(served, tmp_path):
-    """With ``EngineConfig.compile_cache_dir`` set, a second engine serving
+def test_persistent_compile_cache_hits(served, tmp_path, monkeypatch):
+    """With ``EngineConfig.compile_cache`` on, a second engine serving
     the same config after ``jax.clear_caches()`` re-traces its executables
     but loads the XLA binaries from disk: the report shows cache hits,
     near-zero misses, and the replayed streams are bitwise-identical."""
@@ -855,15 +855,18 @@ def test_persistent_compile_cache_hits(served, tmp_path):
              "jax_persistent_cache_min_entry_size_bytes",
              "jax_persistent_cache_min_compile_time_secs")
     prev = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     try:
         def serve():
             eng = _struct_engine(model, params, FixedMaskPolicy(mm, [mask]),
                                  "local", budget=budget, max_new=4,
-                                 cache_dir=str(tmp_path))
+                                 compile_cache=True)
             return eng.run(_reqs(prompts, max_new=4))
 
         rep1 = serve()
         assert rep1.compile_events > 0
+        assert any(tmp_path.iterdir()), "cache not written where the env " \
+            "var points"
         jax.clear_caches()                  # drop in-memory executables
         # first replay: executables compiled BEFORE the cache was enabled
         # (session fixtures, earlier tests) are written — not hit — so
@@ -887,3 +890,26 @@ def test_persistent_compile_cache_hits(served, tmp_path):
         _cc.reset_cache()               # re-latch: later tests cache-free
         from repro.runtime.engine import _CACHE_LISTENER
         _CACHE_LISTENER.pop("dir", None)
+
+
+def test_compile_cache_dir_yields_to_env(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR``, when set, is the cache and
+    ``enable_compile_cache`` points JAX there; unset, the cache is the one
+    fixed ``.jax_cache/`` at the checkout's root."""
+    import os
+
+    from jax._src import compilation_cache as _cc
+    from repro.runtime import engine
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert engine.compile_cache_dir() == os.path.join(root, ".jax_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        assert engine.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        _cc.reset_cache()
+        engine._CACHE_LISTENER.pop("dir", None)

@@ -82,12 +82,12 @@ def test_paged_decode_matches_dense_bitwise(B, H, K, D, pt, S, cap):
     kd = rng.standard_normal((B, S, K, D)).astype(np.float32)
     vd = rng.standard_normal((B, S, K, D)).astype(np.float32)
     table = rng.permutation(n_pages)[: B * P].reshape(B, P).astype(np.int32)
-    k_pages = rng.standard_normal((n_pages, pt, K, D)).astype(np.float32)
-    v_pages = rng.standard_normal((n_pages, pt, K, D)).astype(np.float32)
+    k_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
+    v_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
     for b in range(B):
-        for p in range(P):
-            k_pages[table[b, p]] = kd[b, p * pt:(p + 1) * pt]
-            v_pages[table[b, p]] = vd[b, p * pt:(p + 1) * pt]
+        for p in range(P):       # head-major pages: [K, pt, D] per page
+            k_pages[table[b, p]] = kd[b, p * pt:(p + 1) * pt].swapaxes(0, 1)
+            v_pages[table[b, p]] = vd[b, p * pt:(p + 1) * pt].swapaxes(0, 1)
 
     out = ops.paged_decode_attention(q, jnp.asarray(k_pages),
                                      jnp.asarray(v_pages),
@@ -118,8 +118,8 @@ def test_paged_decode_quantized_matches_dequant_bitwise(B, H, K, D, pt, S,
     lengths = rng.integers(1, S + 1, size=B).astype(np.int32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)).astype(np.float32))
     table = rng.permutation(n_pages)[: B * P].reshape(B, P).astype(np.int32)
-    k_pages = rng.standard_normal((n_pages, pt, K, D)).astype(np.float32)
-    v_pages = rng.standard_normal((n_pages, pt, K, D)).astype(np.float32)
+    k_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
+    v_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
     kq, ks = page_quant(jnp.asarray(k_pages), jnp.int8)
     vq, vs = page_quant(jnp.asarray(v_pages), jnp.int8)
 
@@ -143,8 +143,8 @@ def test_paged_decode_row_isolation():
     lengths = np.asarray([S, S - 3], np.int32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)).astype(np.float32))
     table = np.arange(B * P).reshape(B, P).astype(np.int32)
-    k_pages = rng.standard_normal((n_pages, pt, K, D)).astype(np.float32)
-    v_pages = rng.standard_normal((n_pages, pt, K, D)).astype(np.float32)
+    k_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
+    v_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
     a = ops.paged_decode_attention(q, jnp.asarray(k_pages),
                                    jnp.asarray(v_pages), jnp.asarray(table),
                                    jnp.asarray(lengths))

@@ -287,10 +287,10 @@ def test_page_quant_roundtrip_bound(x):
     from repro.models.attention import page_dequant, page_quant
     arr = np.zeros((max(len(x) // 8, 1) * 8,), np.float32)
     arr[: len(x)] = np.asarray(x[: arr.size], np.float32)
-    page = jnp.asarray(arr.reshape(1, -1, 2, 4))      # [1, pt, K=2, D=4]
+    page = jnp.asarray(arr.reshape(1, 2, -1, 4))      # [1, K=2, pt, D=4]
     q, s = page_quant(page, jnp.int8)
     err = np.abs(np.asarray(page_dequant(q, s) - page))
-    per_head = np.asarray(s)[..., None, :, None]
+    per_head = np.asarray(s)[..., None, None]
     assert (err <= per_head * 0.51 + 1e-6).all()
     q2, s2 = page_quant(page_dequant(q, s), jnp.int8, scale_floor=s)
     assert np.array_equal(np.asarray(q), np.asarray(q2))
@@ -299,7 +299,7 @@ def test_page_quant_roundtrip_bound(x):
         q8, s8 = page_quant(page, fp8)
         err8 = np.abs(np.asarray(page_dequant(q8, s8) - page))
         bound = (np.abs(np.asarray(page)) * 0.0625
-                 + np.asarray(s8)[..., None, :, None] + 1e-6)
+                 + np.asarray(s8)[..., None, None] + 1e-6)
         assert (err8 <= bound).all()
 
 

@@ -117,6 +117,21 @@ def test_compaction_shrinks_params(tiny_model):
     assert len(layout) == L - 1
 
 
+def test_full_mask_compaction_returns_dense_arrays(tiny_model):
+    """The full mask gathers every row in order: compaction hands back the
+    dense stacks themselves (no second copy of the weights), with the
+    default layout."""
+    model, params, _ = tiny_model
+    cfg = model.cfg
+    small, layout = masks.compact_params(params, cfg,
+                                         masks.full_mask(cfg.n_layers))
+    assert layout == decoder.default_layout(cfg)
+    for kind, stack in params["stacks"].items():
+        assert small["stacks"][kind] is stack
+    for a, b in zip(jax.tree.leaves(small), jax.tree.leaves(params)):
+        assert a is b
+
+
 def test_bucket_key_collapses_uniform(tiny_model):
     model, _, _ = tiny_model
     cfg = model.cfg

@@ -158,7 +158,6 @@ def test_server_bucket_cache_reuse(tiny_model):
 def test_int8_error_feedback_allreduce():
     """Inside shard_map on a 1-device mesh: quantized mean ≈ true mean and
     the residual carries the quantization error."""
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_host_mesh
 
@@ -170,16 +169,16 @@ def test_int8_error_feedback_allreduce():
     def f(g, r):
         return compression.compress_allreduce(g, r, ("data",))
 
-    mean, new_r = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                            out_specs=(P(), P()), check_vma=False)(g, r)
+    mean, new_r = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                out_specs=(P(), P()), check_vma=False)(g, r)
     err = np.abs(np.asarray(mean["w"]) - np.asarray(g["w"]))
     scale = np.abs(np.asarray(g["w"])).max() / 127.0
     assert err.max() <= scale * 0.51 + 1e-6
     np.testing.assert_allclose(np.asarray(new_r["w"]),
                                np.asarray(g["w"] - mean["w"]), atol=1e-6)
     # second round with residual: cumulative error shrinks (error feedback)
-    mean2, _ = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=(P(), P()), check_vma=False)(g, new_r)
+    mean2, _ = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=(P(), P()), check_vma=False)(g, new_r)
     total = np.asarray(mean["w"] + mean2["w"])
     np.testing.assert_allclose(total, 2 * np.asarray(g["w"]),
                                atol=2 * scale)
