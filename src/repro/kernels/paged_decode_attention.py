@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +128,18 @@ def _kernel_quant(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                 scale=scale, softcap=softcap, page_tokens=page_tokens)
 
 
+def grid(batch: int, kv_heads: int, max_pages: int) -> Tuple[int, int, int]:
+    """The kernel's grid: each (row, kv head) walks all ``max_pages``
+    entries of its page-table row, whatever the row's length."""
+    return (batch, kv_heads, max_pages)
+
+
+def pages_walked(batch: int, max_pages: int) -> int:
+    """Pages one call visits per kv head, as :func:`grid` walks them."""
+    rows, _, pages = grid(batch, 1, max_pages)
+    return rows * pages
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            k_scales=None, v_scales=None,
                            softcap: float = 0.0, interpret: bool = False):
@@ -182,7 +195,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                                softcap=softcap, page_tokens=page_tokens)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, K, max_pages),
+        grid=grid(B, K, max_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, D), q_map),
         scratch_shapes=[

@@ -322,6 +322,7 @@ def main():
             kept = int(r.mask.sum())
             print(f"{r.rid}: kept {kept}/{len(r.mask)} blocks  "
                   f"queue {r.queue_delay_s*1e3:.0f}ms  "
+                  f"ttft {r.ttft_s*1e3:.0f}ms  "
                   f"decide {r.decide_s*1e3:.0f}ms"
                   f"{' (memo)' if r.cached_decision else ''}  "
                   f"fits={r.fits}")
@@ -354,6 +355,20 @@ def main():
           f"measured frag {rep.measured_frag:.2f}, "
           f"overcommits {int(rep.pool['overcommit_events'])}")
     print("engine stats:", engine.stats())
+    # the run's own spans and counters (repro.runtime.tracing)
+    tr = rep.trace
+    print("spans (count, mean ms): " + ", ".join(
+        f"{name} {int(n)}/{sec / n * 1e3:.2f}"
+        for name, (n, sec) in sorted(tr.span_totals.items())))
+    ct = tr.counter_totals
+    print(f"prefill: {int(ct.get('chunk', 0))} chunks, "
+          f"{int(ct.get('chunk.tokens', 0))} tokens")
+    if ct.get("launch"):
+        print(f"paged decode: {int(ct['launch'])} launches, rows "
+              f"{int(ct['launch.rows_occupied'])} occupied of "
+              f"{int(ct['launch.rows_stepped'])} stepped, pages "
+              f"{int(ct['launch.pages_with_tokens'])} with tokens of "
+              f"{int(ct['launch.pages_walked'])} walked")
 
 
 if __name__ == "__main__":

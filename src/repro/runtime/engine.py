@@ -57,6 +57,7 @@ are reported back to the policy via ``feedback()``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -74,6 +75,7 @@ from repro.runtime.kv_pool import (KVPool, default_page_bytes,
                                    resolve_kv_dtype)
 from repro.runtime.scheduler import (Scheduler, VictimCandidate,
                                      make_scheduler)
+from repro.runtime.tracing import Recorder
 
 __all__ = ["EngineConfig", "EngineRequest", "RequestResult", "EngineReport",
            "RAPEngine", "compile_cache_dir", "enable_compile_cache"]
@@ -369,6 +371,11 @@ class RequestResult:
     # time to first token, measured from ARRIVAL (so it decomposes as
     # queue_delay_s + prefill time; -1.0 for rejected requests)
     ttft_s: float = -1.0
+    # token deliveries (engine-clock time, tokens): the prefill's first
+    # token, then one entry per decode horizon; Σ tokens == tokens.shape[1]
+    # (empty for rejected requests and those cancelled before any token)
+    deliveries: List[Tuple[float, int]] = \
+        dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -423,6 +430,8 @@ class EngineReport:
     # scenario harnesses use these to window per-phase goodput
     budget_events: List[Tuple[float, float]] = \
         dataclasses.field(default_factory=list)
+    # the run's host spans and counters (repro.runtime.tracing)
+    trace: Optional[Recorder] = None
 
     def result(self, rid: str) -> RequestResult:
         for r in self.results:
@@ -444,7 +453,8 @@ class _Running:
     bucket: Tuple
     # token-emission events (virtual-clock time, tokens appended): the
     # first entry is the prefill's token #1 (TTFT anchor); each decode
-    # horizon appends one entry covering its H tokens (ITL samples)
+    # horizon appends one entry covering its H tokens (ITL samples). It
+    # becomes the result's ``deliveries``
     events: List[Tuple[float, int]] = dataclasses.field(default_factory=list)
     # times this request was preempted (routes its ITL samples to the
     # report's itl_preempted pool instead of itl)
@@ -578,6 +588,12 @@ class RAPEngine:
         self._preempted_count = 0
         self._spilled_bytes = 0.0
         self._stall_ticks = 0
+        self._new_trace()
+
+    def _new_trace(self) -> None:
+        """One recorder per run, shared with the executor."""
+        self.trace = Recorder()
+        self.executor.tracer = self.trace
 
     # ------------------------------------------------------------ capacity
     def ensure_capacity(self, batch: int, total_len: int) -> None:
@@ -682,13 +698,15 @@ class RAPEngine:
         self._cache_hits_at_run_start = _CACHE_EVENTS["hits"]
         self._cache_misses_at_run_start = _CACHE_EVENTS["misses"]
         self._launch_s_at_run_start = getattr(self.executor, "launch_s", 0.0)
+        self._new_trace()
         self._skew = 0.0
         self._t0 = time.perf_counter()
         self.executor.evict_all()             # previous run's occupants
         try:
             while (self._pending or len(self.scheduler) or self._running
                    or self._prefilling or self._preempted):
-                self._tick(on_tick)
+                with self.trace.span("rap.tick"):
+                    self._tick(on_tick)
         except BaseException:
             # a run that raises mid-serve must not leak pool ledger
             # entries / spilled pages / seated slots into the next run()
@@ -734,7 +752,8 @@ class RAPEngine:
             spilled_mb=self._spilled_bytes / 1e6,
             resume_latency=_lat_summarize(self._resume_samples),
             itl_preempted=_lat_summarize(self._itl_preempted_samples),
-            budget_events=list(self._budget_events))
+            budget_events=list(self._budget_events),
+            trace=self.trace)
 
     # ------------------------------------------------------------ one tick
     def _tick(self, on_tick: Any = None) -> None:
@@ -767,18 +786,25 @@ class RAPEngine:
         cancellation safe: a restored request's slots and pages were free
         at launch, and a cancelled request simply vanishes from
         ``_running`` so fold-back skips it (over-generated horizon tokens
-        are truncated exactly like a completion's)."""
+        are truncated exactly like a completion's).
+
+        ``run`` wraps each tick in a ``rap.tick`` span; the phases are its
+        children (``repro.runtime.tracing``)."""
+        tr = self.trace
         now = self._now()
-        self._eval_budget(now)
-        self._maybe_preempt(now)
-        plan = self.scheduler.schedule(now, running=list(self._running))
+        with tr.span("rap.budget"):
+            self._eval_budget(now)
+            self._maybe_preempt(now)
+        with tr.span("rap.schedule"):
+            plan = self.scheduler.schedule(now, running=list(self._running))
         backlog = (len(self.scheduler) > 0
                    or bool(self._pending
                            and self._pending[0].arrival_t <= now))
         launches = self._launch_decode(plan.decode, backlog=backlog)
         # ---- host phase (device scans in flight from here to finish) ----
         if on_tick is not None:
-            on_tick(self)
+            with tr.span("rap.on_tick"):
+                on_tick(self)
         while self._pending and self._pending[0].arrival_t <= now:
             req = self._pending.pop(0)
             if (req.rid in self.scheduler or req.rid in self._running
@@ -801,8 +827,11 @@ class RAPEngine:
         # admission plan: try candidates in the scheduler's order; a
         # deferral ends the loop so the order is never overtaken in-tick
         deferred = None
-        for req in self.scheduler.schedule(now).admit:
-            verdict = self._try_admit(req)
+        with tr.span("rap.schedule"):
+            admit = self.scheduler.schedule(now).admit
+        for req in admit:
+            with tr.span("rap.admit", rid=req.rid):
+                verdict = self._try_admit(req)
             if verdict == "defer":
                 deferred = req
                 break
@@ -818,7 +847,8 @@ class RAPEngine:
         self._advance_prefills()
         # ---- finish: the tick's one sync point --------------------------
         if launches:
-            self._finish_decode(launches)
+            with tr.span("rap.foldback"):
+                self._finish_decode(launches)
         if self._running or self._prefilling:
             self._stall_ticks = 0
         else:
@@ -954,9 +984,10 @@ class RAPEngine:
         pages to the pool's host store, release its reservation."""
         rid = run.req.rid
         cache_len = run.group.cache_len
-        state = self.executor.spill_state(run.group, run.slots)
-        run.group.evict(run.slots)
-        self._spilled_bytes += self.pool.spill(rid)
+        with self.trace.span("rap.spill", rid=rid):
+            state = self.executor.spill_state(run.group, run.slots)
+            run.group.evict(run.slots)
+            self._spilled_bytes += self.pool.spill(rid)
         del self._running[rid]
         run.preempt_count += 1
         self._preempted[rid] = _Preempted(run=run, state=state,
@@ -971,8 +1002,9 @@ class RAPEngine:
         if not self._preempted:
             return
         kv_budget = self._kv_budget()
-        for rid in reversed(list(self._preempted)):
-            self._resume_one(rid, kv_budget)
+        with self.trace.span("rap.resume"):
+            for rid in reversed(list(self._preempted)):
+                self._resume_one(rid, kv_budget)
 
     def _resume_one(self, rid: str, kv_budget: float, *,
                     force: bool = False) -> bool:
@@ -988,10 +1020,11 @@ class RAPEngine:
         free = group.free_slots()
         if len(free) < b:
             return False
-        rows = self.pool.restore(rid)
         slots = free[:b]
-        self.executor.restore_state(group, slots, rid, p.state,
-                                    p.run.decision.mask, rows)
+        with self.trace.span("rap.restore", rid=rid):
+            rows = self.pool.restore(rid)
+            self.executor.restore_state(group, slots, rid, p.state,
+                                        p.run.decision.mask, rows)
         run = p.run
         run.group, run.slots = group, slots
         if force:
@@ -1083,7 +1116,8 @@ class RAPEngine:
             fits=(d.fits if d is not None else False),
             cached_decision=(d.cached if d is not None else False),
             peak_bytes=(d.peak_bytes if d is not None else 0.0),
-            kv_bytes=kv_bytes, reason="cancelled", ttft_s=ttft))
+            kv_bytes=kv_bytes, reason="cancelled", ttft_s=ttft,
+            deliveries=list(events or ())))
 
     # --------------------------------------------------------- fault safety
     def _abort_cleanup(self) -> None:
@@ -1149,13 +1183,15 @@ class RAPEngine:
             # through exactly so decisions match the historical contract)
             eff = np.floor(eff / quantum + 1e-9) * quantum
         cache_len = self._cache_len(total)
-        d = self._sticky_decision(b, total, eff, cache_len)
-        if d is None:
-            d = self.policy.observe(PolicyState(
-                batch=b, total_len=total, budget_bytes=eff,
-                reserved_bytes=self.pool.bytes_reserved,
-                capacity_bytes=self.pool.acct.capacity_bytes,
-                n_running=len(self._running), now=self._now()))
+        with self.trace.span("rap.policy") as sp:
+            d = self._sticky_decision(b, total, eff, cache_len)
+            if d is None:
+                d = self.policy.observe(PolicyState(
+                    batch=b, total_len=total, budget_bytes=eff,
+                    reserved_bytes=self.pool.bytes_reserved,
+                    capacity_bytes=self.pool.acct.capacity_bytes,
+                    n_running=len(self._running), now=self._now()))
+            sp.set(cached=int(d.cached))
         kv_bytes = self.mm.state_bytes(d.mask, b, total)
         if not self._paged:
             # slot-path admission charges QUANTIZED bytes: the analytical
@@ -1247,8 +1283,9 @@ class RAPEngine:
                 admitted_t=admitted_t, kv_bytes=kv_bytes, max_new=max_new,
                 bucket=bucket, task=task)
             return "admitted"
-        first = self.executor.prefill_into(group, slots, req.rid, prompt,
-                                           d.mask)
+        with self._prefill_chunk(req.rid, 0, S):
+            first = self.executor.prefill_into(group, slots, req.rid, prompt,
+                                               d.mask)
         run = _Running(req=req, decision=d, group=group, slots=slots,
                        admitted_t=admitted_t, kv_bytes=kv_bytes,
                        max_new=max_new, out=[first], bucket=bucket,
@@ -1306,7 +1343,9 @@ class RAPEngine:
         and stamps its first-token event."""
         for rid in list(self._prefilling):
             pf = self._prefilling[rid]
-            first = self.executor.prefill_step(pf.task)
+            task = pf.task
+            with self._prefill_chunk(rid, task.pos, task.widths[task.step]):
+                first = self.executor.prefill_step(task)
             if first is None:
                 continue
             del self._prefilling[rid]
@@ -1318,6 +1357,14 @@ class RAPEngine:
             self._running[rid] = run
             if run.max_new <= len(run.out):
                 self._complete(run)
+
+    @contextlib.contextmanager
+    def _prefill_chunk(self, rid: str, start: int, width: int):
+        """A ``rap.prefill_chunk`` span and its chunk record."""
+        with self.trace.span("rap.prefill_chunk", rid=rid, start=start,
+                             width=width) as sp:
+            yield
+        self.trace.chunk(sp.end, rid, start, width)
 
     # --------------------------------------------------------------- decode
     def _launch_decode(self, decode_plan: Optional[List[str]],
@@ -1445,20 +1492,12 @@ class RAPEngine:
             arrival_t=run.req.arrival_t, admitted_t=run.admitted_t,
             finished_t=now, queue_delay_s=run.admitted_t - run.req.arrival_t,
             decide_s=d.latency_s, fits=d.fits, cached_decision=d.cached,
-            peak_bytes=d.peak_bytes, kv_bytes=run.kv_bytes, ttft_s=ttft)
+            peak_bytes=d.peak_bytes, kv_bytes=run.kv_bytes, ttft_s=ttft,
+            deliveries=run.events)
         self._results.append(result)
         del self._running[run.req.rid]
         self.policy.feedback(result)
 
     # ---------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = dict(self.executor.stats())
-        # per-request TTFT decomposition (queueing vs prefill) for the
-        # most recent run: ttft_s − queue_delay_s is time from admission
-        # to first token, i.e. the prefill share
-        out["requests"] = {
-            r.rid: {"queue_delay_s": r.queue_delay_s, "ttft_s": r.ttft_s,
-                    "prefill_s": max(r.ttft_s - r.queue_delay_s, 0.0)}
-            for r in self._results
-            if r.status == "done" and r.ttft_s >= 0.0}
-        return out
+        return dict(self.executor.stats())

@@ -58,7 +58,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -66,7 +65,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import masks as masks_lib
+from repro.kernels import paged_decode_attention as paged_kernel
 from repro.models import attention, decoder
+from repro.runtime import tracing
 from repro.runtime.kv_pool import resolve_kv_dtype
 
 __all__ = ["ModelExecutor", "SlotGroup", "LocalExecutor", "PagedExecutor",
@@ -148,7 +149,7 @@ class _InFlightHorizon:
 # jitted executable per update kind replaces the chain with a single
 # launch; donation makes the updates in-place.
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-def _paged_place_upd(table, pos, tok, gates, sidx, rows, plen, first, cols):
+def rap_paged_place(table, pos, tok, gates, sidx, rows, plen, first, cols):
     return (table.at[sidx].set(rows),
             pos.at[sidx].set(plen),
             tok.at[sidx].set(first),
@@ -156,7 +157,7 @@ def _paged_place_upd(table, pos, tok, gates, sidx, rows, plen, first, cols):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-def _paged_evict_upd(table, pos, tok, gates, sidx, scratch):
+def rap_paged_evict(table, pos, tok, gates, sidx, scratch):
     return (table.at[sidx].set(scratch),
             pos.at[sidx].set(0),
             tok.at[sidx].set(0),
@@ -164,12 +165,12 @@ def _paged_evict_upd(table, pos, tok, gates, sidx, scratch):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _paged_grant_upd(table, rows, cols, vals):
+def rap_paged_grant(table, rows, cols, vals):
     return table.at[rows, cols].set(vals)
 
 
-def _slot_place_body(cache, tokens, req_cache, sidx, plen, first, cols,
-                     gates):
+def rap_slot_place(cache, tokens, req_cache, sidx, plen, first, cols,
+                   gates):
     out = {}
     for k, v in cache.items():
         if k == "pos":
@@ -186,7 +187,13 @@ def _slot_place_body(cache, tokens, req_cache, sidx, plen, first, cols,
 
 # undecorated body kept separate: ShardedSlotGroup re-jits it with explicit
 # output shardings so placement cannot silently re-shard the resident state
-_slot_place_upd = jax.jit(_slot_place_body, donate_argnums=(0, 1, 7))
+_slot_place_upd = jax.jit(rap_slot_place, donate_argnums=(0, 1, 7))
+
+
+def _mark_compile(sp, executor, compiles_before: int, key: str) -> None:
+    """Tag a dispatch span that mints a new executable."""
+    if executor.compile_events != compiles_before:
+        sp.set(compiled=1, key=key)
 
 
 # distinct occupancy patterns a group may cache device index vectors for;
@@ -350,18 +357,18 @@ class SlotGroup:
         h = int(horizon)
         cfg, layout_c, gated = self._mcfg, self.layout, self.gated
         if gated:
-            def fn(p, cache, tok, gates):
+            def rap_slot_decode_horizon(p, cache, tok, gates):
                 toks, cache = decoder.decode_horizon(
                     p, cfg, cache, tok, h,
                     gates={"mixer": gates[0], "ffn": gates[1]},
                     layout=layout_c)
                 return toks, cache, toks[:, -1:]
         else:
-            def fn(p, cache, tok):
+            def rap_slot_decode_horizon(p, cache, tok):
                 toks, cache = decoder.decode_horizon(p, cfg, cache, tok, h,
                                                      layout=layout_c)
                 return toks, cache, toks[:, -1:]
-        return fn
+        return rap_slot_decode_horizon
 
     def _horizon_fn(self, horizon: int, bucketed: bool):
         """Jitted fused horizon, one executable family per (H, bucketed).
@@ -404,15 +411,17 @@ class SlotGroup:
                     return toks, out, tok
 
                 if gated:
-                    @functools.partial(jax.jit, donate_argnums=(1, 2))
-                    def fn(p, cache, tok, gates, iidx):
+                    def rap_slot_decode_horizon_bucketed(p, cache, tok,
+                                                         gates, iidx):
                         return gather_scan_scatter(p, cache, tok, gates,
                                                    iidx)
                 else:
-                    @functools.partial(jax.jit, donate_argnums=(1, 2))
-                    def fn(p, cache, tok, iidx):
+                    def rap_slot_decode_horizon_bucketed(p, cache, tok,
+                                                         iidx):
                         return gather_scan_scatter(p, cache, tok, None,
                                                    iidx)
+                fn = jax.jit(rap_slot_decode_horizon_bucketed,
+                             donate_argnums=(1, 2))
             self._hfns[key] = fn
         return self._hfns[key]
 
@@ -478,7 +487,10 @@ class ModelExecutor:
     new executables (prefill shapes + decode (batch, horizon) buckets);
     ``launch_s`` accumulates wall time spent inside compiled-executable
     launches and their read-backs, so benchmarks can separate host
-    orchestration overhead from device compute.
+    orchestration overhead from device compute: it is the summed duration
+    of the ``rap.dispatch`` and ``rap.readback`` spans that ``tracer`` (a
+    :class:`~repro.runtime.tracing.Recorder`, the engine's during a run)
+    records around them.
 
     ``paged`` marks backends whose KV lives in a :class:`KVPool`'s physical
     page arrays — the engine switches admission to the token-granular pool
@@ -536,6 +548,14 @@ class ModelExecutor:
         work seated a new request into a then-free padding slot) are left
         untouched."""
         raise NotImplementedError
+
+    def _readback(self, launch: "_InFlightHorizon") -> np.ndarray:
+        """The launch's single device→host sync, as a ``rap.readback``
+        span that ``launch_s`` adds up."""
+        with self.tracer.span("rap.readback") as sp:
+            nxt = np.asarray(launch.toks_dev)
+        self.launch_s += sp.seconds
+        return nxt
 
     def decode_horizon(self, group: SlotGroup,
                        horizon: int) -> Tuple[np.ndarray, bool]:
@@ -625,6 +645,7 @@ class LocalExecutor(ModelExecutor):
         self.decode_buckets = tuple(int(b) for b in decode_buckets or ())
         self.compile_events = 0
         self.launch_s = 0.0
+        self.tracer = tracing.Recorder()
         # structural groups are keyed by (gather_key, cache_len) — the
         # EXACT parameter rows they decode with — never by bucket_key
         # alone, which aliases different-layer drops onto one signature
@@ -763,17 +784,15 @@ class LocalExecutor(ModelExecutor):
                 # same-signature buckets share this executable: their
                 # (compacted) layouts are identical tuples and the params
                 # arrive as jit arguments, never closure constants
-                @jax.jit
-                def fn(p, tokens, gm, gf):
+                def rap_slot_prefill(p, tokens, gm, gf):
                     return decoder.prefill(p, cfg, tokens, max_len,
                                            gates={"mixer": gm, "ffn": gf},
                                            layout=layout, kv_dtype=kv_dtype)
             else:
-                @jax.jit
-                def fn(p, tokens):
+                def rap_slot_prefill(p, tokens):
                     return decoder.prefill(p, cfg, tokens, max_len,
                                            layout=layout, kv_dtype=kv_dtype)
-            self._prefill_fns[key] = fn
+            self._prefill_fns[key] = jax.jit(rap_slot_prefill)
             self.compile_events += 1
         return self._prefill_fns[key]
 
@@ -782,15 +801,17 @@ class LocalExecutor(ModelExecutor):
         """Prefill the request and seat it; returns token #1 per row [b]."""
         b, S = prompt.shape
         tokens = jnp.asarray(prompt, jnp.int32)
+        c0 = self.compile_events
         fn = self._prefill_fn(group, b, S)
-        t0 = time.perf_counter()
-        if group.gated:
-            cols = _gate_cols(mask, group.gate_rows)
-            logits, cache = fn(group.params, tokens, cols[0], cols[1])
-        else:
-            logits, cache = fn(group.params, tokens)
-        first = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        self.launch_s += time.perf_counter() - t0
+        with self.tracer.span("rap.dispatch") as sp:
+            _mark_compile(sp, self, c0, f"prefill:{group.key}:{b}x{S}")
+            if group.gated:
+                cols = _gate_cols(mask, group.gate_rows)
+                logits, cache = fn(group.params, tokens, cols[0], cols[1])
+            else:
+                logits, cache = fn(group.params, tokens)
+            first = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        self.launch_s += sp.seconds
         cache.pop("pos")
         group.place(rid, slots, cache, mask if group.gated else None, S,
                     first)
@@ -814,20 +835,19 @@ class LocalExecutor(ModelExecutor):
         if key not in self._prefill_fns:
             cfg, layout = self.mcfg, group.layout
             if group.gated:
-                @functools.partial(jax.jit, donate_argnums=(1,))
-                def fn(p, attn, tokens, start, gm, gf):
+                def rap_slot_prefill_chunk(p, attn, tokens, start, gm, gf):
                     logits, cache = decoder.prefill_chunk(
                         p, cfg, {"attn": attn}, tokens, start,
                         gates={"mixer": gm, "ffn": gf}, layout=layout)
                     return logits, cache["attn"]
             else:
-                @functools.partial(jax.jit, donate_argnums=(1,))
-                def fn(p, attn, tokens, start):
+                def rap_slot_prefill_chunk(p, attn, tokens, start):
                     logits, cache = decoder.prefill_chunk(
                         p, cfg, {"attn": attn}, tokens, start,
                         layout=layout)
                     return logits, cache["attn"]
-            self._prefill_fns[key] = fn
+            self._prefill_fns[key] = jax.jit(rap_slot_prefill_chunk,
+                                             donate_argnums=(1,))
             self.compile_events += 1
         return self._prefill_fns[key]
 
@@ -856,22 +876,25 @@ class LocalExecutor(ModelExecutor):
         c = task.widths[task.step]
         tokens = jnp.asarray(task.prompt[:, task.pos:task.pos + c],
                              jnp.int32)
+        c0 = self.compile_events
         fn = self._chunk_fn(group, b, c)
-        t0 = time.perf_counter()
-        if group.gated:
-            logits, task.state = fn(group.params, task.state, tokens,
-                                    np.int32(task.pos),
-                                    task.gates["mixer"], task.gates["ffn"])
-        else:
-            logits, task.state = fn(group.params, task.state, tokens,
-                                    np.int32(task.pos))
-        task.pos += c
-        task.step += 1
-        if not task.done:
-            self.launch_s += time.perf_counter() - t0
+        with self.tracer.span("rap.dispatch") as sp:
+            _mark_compile(sp, self, c0, f"chunk:{group.key}:{b}x{c}")
+            if group.gated:
+                logits, task.state = fn(group.params, task.state, tokens,
+                                        np.int32(task.pos),
+                                        task.gates["mixer"],
+                                        task.gates["ffn"])
+            else:
+                logits, task.state = fn(group.params, task.state, tokens,
+                                        np.int32(task.pos))
+            task.pos += c
+            task.step += 1
+            first = (np.asarray(jnp.argmax(logits, axis=-1)
+                                .astype(jnp.int32)) if task.done else None)
+        self.launch_s += sp.seconds
+        if first is None:
             return None
-        first = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        self.launch_s += time.perf_counter() - t0
         group.place(task.rid, task.slots, {"attn": task.state},
                     task.mask if group.gated else None, S, first)
         task.state = None
@@ -911,23 +934,27 @@ class LocalExecutor(ModelExecutor):
     # -------------------------------------------------------------- decode
     def decode_launch(self, group: SlotGroup,
                       horizon: int) -> _InFlightHorizon:
-        t0 = time.perf_counter()
-        toks_dev, idx, new = group.launch_horizon(horizon,
-                                                  self.decode_buckets)
-        self.launch_s += time.perf_counter() - t0
-        if new:
-            self.compile_events += 1
-        occ = (list(group.occupants) if idx is None
-               else [group.occupants[s] for s in idx])
+        tr = self.tracer
+        with tr.span("rap.decode_launch", horizon=int(horizon)) as dl:
+            with tr.span("rap.dispatch") as sp:
+                toks_dev, idx, new = group.launch_horizon(
+                    horizon, self.decode_buckets)
+                width = group.n_slots if idx is None else len(idx)
+                if new:
+                    self.compile_events += 1
+                    sp.set(compiled=1,
+                           key=f"decode:{group.key}:{width}x{horizon}")
+            self.launch_s += sp.seconds
+            dl.set(width=width)
+            occ = (list(group.occupants) if idx is None
+                   else [group.occupants[s] for s in idx])
         return _InFlightHorizon(group=group, horizon=int(horizon),
                                 toks_dev=toks_dev, idx=idx, occupants=occ,
                                 new=new)
 
     def decode_finish(self,
                       launch: _InFlightHorizon) -> Tuple[np.ndarray, bool]:
-        t0 = time.perf_counter()
-        nxt = np.asarray(launch.toks_dev)  # the single device→host sync
-        self.launch_s += time.perf_counter() - t0
+        nxt = self._readback(launch)
         if launch.idx is None:
             return nxt, launch.new
         out = np.zeros((launch.group.n_slots, launch.horizon), np.int32)
@@ -1060,7 +1087,7 @@ class PagedGroup:
         cols = np.stack([np.asarray(gm, np.float32),
                          np.asarray(gf, np.float32)])
         (self.table_dev, self.pos_dev, self.tokens_dev,
-         self.gates_dev) = _paged_place_upd(
+         self.gates_dev) = rap_paged_place(
             self.table_dev, self.pos_dev, self.tokens_dev, self.gates_dev,
             self.iidx(slots), full_rows, int(prompt_len),
             np.asarray(first, np.int32), cols)
@@ -1075,7 +1102,7 @@ class PagedGroup:
         cols = np.asarray([e[1] for e in entries], np.int32)
         vals = np.asarray([e[2] for e in entries], np.int32)
         self.table[rows, cols] = vals
-        self.table_dev = _paged_grant_upd(self.table_dev, rows, cols, vals)
+        self.table_dev = rap_paged_grant(self.table_dev, rows, cols, vals)
 
     def evict(self, slots: List[int]) -> None:
         self.reserved.difference_update(slots)
@@ -1086,7 +1113,7 @@ class PagedGroup:
             self.tokens[s] = 0
         if slots:
             (self.table_dev, self.pos_dev, self.tokens_dev,
-             self.gates_dev) = _paged_evict_upd(
+             self.gates_dev) = rap_paged_evict(
                 self.table_dev, self.pos_dev, self.tokens_dev,
                 self.gates_dev, self.iidx(slots), self.scratch_page)
 
@@ -1177,6 +1204,7 @@ class PagedExecutor(ModelExecutor):
         self.decode_buckets = tuple(int(b) for b in decode_buckets or ())
         self.compile_events = 0
         self.launch_s = 0.0
+        self.tracer = tracing.Recorder()
         self.pool = None               # bound per engine run
         # "masked" -> the single gated group; structural mode keys groups
         # by gather_key (exact parameter rows), as in LocalExecutor
@@ -1314,7 +1342,7 @@ class PagedExecutor(ModelExecutor):
             # read. Same-signature buckets share this executable (params
             # are jit arguments; equal-signature layouts are identical).
             @functools.partial(jax.jit, donate_argnums=(4,))
-            def fn(p, tokens, gm, gf, pools, rows):
+            def rap_paged_prefill(p, tokens, gm, gf, pools, rows):
                 logits, cache = decoder.prefill(
                     p, cfg, tokens, npg * pt,
                     gates={"mixer": gm, "ffn": gf}, layout=layout,
@@ -1339,7 +1367,7 @@ class PagedExecutor(ModelExecutor):
                     pools["v"] = vp.at[:Lp, rows].set(v.astype(vp.dtype))
                 return logits, pools
 
-            self._prefill_fns[key] = fn
+            self._prefill_fns[key] = rap_paged_prefill
             self.compile_events += 1
         return self._prefill_fns[key]
 
@@ -1351,18 +1379,20 @@ class PagedExecutor(ModelExecutor):
         rows = self.pool.row_pages(rid)            # [b][npg] page ids
         npg = len(rows[0])
         rows_np = np.asarray(rows, np.int32)
+        c0 = self.compile_events
         fn = self._prefill_fn(group, b, S, npg)
         # one gate-column stack serves both the jitted call and the
         # group's resident gate columns
         cols = _gate_cols(mask, group.gate_rows)
-        t0 = time.perf_counter()
-        logits, pools = fn(self._group_params(group),
-                           jnp.asarray(prompt, jnp.int32),
-                           cols[0], cols[1], self._pool_leaves(),
-                           jnp.asarray(rows_np))
-        self._store_leaves(pools)
-        first = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        self.launch_s += time.perf_counter() - t0
+        with self.tracer.span("rap.dispatch") as sp:
+            _mark_compile(sp, self, c0, f"prefill:{group.key}:{b}x{S}")
+            logits, pools = fn(self._group_params(group),
+                               jnp.asarray(prompt, jnp.int32),
+                               cols[0], cols[1], self._pool_leaves(),
+                               jnp.asarray(rows_np))
+            self._store_leaves(pools)
+            first = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        self.launch_s += sp.seconds
         group.place(rid, slots, rows_np, S, first, cols[0], cols[1])
         return first
 
@@ -1386,14 +1416,15 @@ class PagedExecutor(ModelExecutor):
             layout = group.layout
 
             @functools.partial(jax.jit, donate_argnums=(1,))
-            def fn(p, pools, table, tokens, start, gm, gf):
+            def rap_paged_prefill_chunk(p, pools, table, tokens, start, gm,
+                                        gf):
                 logits, pools = decoder.paged_prefill_chunk(
                     p, cfg, pools, table, tokens, start,
                     scratch_page=scratch,
                     gates={"mixer": gm, "ffn": gf}, layout=layout)
                 return logits, pools
 
-            self._prefill_fns[key] = fn
+            self._prefill_fns[key] = rap_paged_prefill_chunk
             self.compile_events += 1
         return self._prefill_fns[key]
 
@@ -1424,20 +1455,23 @@ class PagedExecutor(ModelExecutor):
         table = np.full((b, self.max_row_pages), self.pool.scratch_page,
                         np.int32)
         table[:, :len(rows[0])] = np.asarray(rows, np.int32)
+        c0 = self.compile_events
         fn = self._chunk_fn(group, b, c)
-        t0 = time.perf_counter()
-        logits, pools = fn(
-            self._group_params(group), self._pool_leaves(), jnp.asarray(table),
-            jnp.asarray(task.prompt[:, task.pos:task.pos + c], jnp.int32),
-            np.int32(task.pos), task.gates["mixer"], task.gates["ffn"])
-        self._store_leaves(pools)
-        task.pos += c
-        task.step += 1
-        if not task.done:
-            self.launch_s += time.perf_counter() - t0
+        with self.tracer.span("rap.dispatch") as sp:
+            _mark_compile(sp, self, c0, f"chunk:{group.key}:{b}x{c}")
+            logits, pools = fn(
+                self._group_params(group), self._pool_leaves(),
+                jnp.asarray(table),
+                jnp.asarray(task.prompt[:, task.pos:task.pos + c], jnp.int32),
+                np.int32(task.pos), task.gates["mixer"], task.gates["ffn"])
+            self._store_leaves(pools)
+            task.pos += c
+            task.step += 1
+            first = (np.asarray(jnp.argmax(logits, axis=-1)
+                                .astype(jnp.int32)) if task.done else None)
+        self.launch_s += sp.seconds
+        if first is None:
             return None
-        first = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        self.launch_s += time.perf_counter() - t0
         rows_np = np.asarray(self.pool.row_pages(rid), np.int32)
         group.place(rid, task.slots, rows_np, S, first,
                     np.asarray(task.gates["mixer"]),
@@ -1486,16 +1520,19 @@ class PagedExecutor(ModelExecutor):
 
             if not bucketed:
                 @functools.partial(jax.jit, donate_argnums=(1, 3, 4))
-                def fn(p, pools, table, pos, tok, gates):
+                def rap_paged_decode_horizon(p, pools, table, pos, tok,
+                                             gates):
                     toks, pools, pos = decoder.paged_decode_horizon(
                         p, cfg, pools, table, pos,
                         tok[:, None], h,
                         gates={"mixer": gates[0], "ffn": gates[1]},
                         impl=impl, layout=layout)
                     return toks, pools, pos, toks[:, -1]
+                fn = rap_paged_decode_horizon
             else:
                 @functools.partial(jax.jit, donate_argnums=(1, 3, 4))
-                def fn(p, pools, table, pos, tok, gates, iidx):
+                def rap_paged_decode_horizon_bucketed(p, pools, table, pos,
+                                                      tok, gates, iidx):
                     g = gates[:, :, iidx]
                     toks, pools, pos_out = decoder.paged_decode_horizon(
                         p, cfg, pools, table[iidx], pos[iidx],
@@ -1505,6 +1542,7 @@ class PagedExecutor(ModelExecutor):
                     pos = pos.at[iidx].set(pos_out)
                     tok = tok.at[iidx].set(toks[:, -1])
                     return toks, pools, pos, tok
+                fn = rap_paged_decode_horizon_bucketed
 
             self._hfns[key] = fn
         return self._hfns[key]
@@ -1574,22 +1612,38 @@ class PagedExecutor(ModelExecutor):
     def decode_launch(self, group: PagedGroup,
                       horizon: int) -> _InFlightHorizon:
         """Bulk page pre-grant + one fused launch, no sync: the host is
-        free to schedule/admit while the scan runs on device."""
-        self.pre_extend_horizon(group, horizon)
-        t0 = time.perf_counter()
-        toks_dev, idx, new = self.launch_horizon(group, horizon)
-        self.launch_s += time.perf_counter() - t0
-        return _InFlightHorizon(group=group, horizon=int(horizon),
-                                toks_dev=toks_dev, idx=idx,
-                                occupants=[group.occupants[s] for s in idx],
-                                new=new)
+        free to schedule/admit while the scan runs on device. Records the
+        launch's page walk (:class:`~repro.runtime.tracing.Launch`)."""
+        tr, h = self.tracer, int(horizon)
+        with tr.span("rap.decode_launch", horizon=h) as dl:
+            with tr.span("rap.page_grant"):
+                self.pre_extend_horizon(group, h)
+            with tr.span("rap.dispatch") as sp:
+                toks_dev, idx, new = self.launch_horizon(group, h)
+                if new:
+                    sp.set(compiled=1,
+                           key=f"decode:{group.key}:{len(idx)}x{h}")
+            self.launch_s += sp.seconds
+            dl.set(width=len(idx))
+            occupants = [group.occupants[s] for s in idx]
+            rows = [s for s, o in zip(idx, occupants) if o is not None]
+            pt = self.pool.tokens_per_page
+            useful = sum(
+                -(-min(int(group.pos[s]) + h,
+                       self.pool.seq_tokens(group.occupants[s])) // pt)
+                for s in rows)
+            tr.launch(t=sp.end, horizon=h, rows_stepped=len(idx),
+                      rows_occupied=len(rows),
+                      pages_walked=paged_kernel.pages_walked(
+                          len(idx), self.max_row_pages),
+                      pages_with_tokens=useful)
+        return _InFlightHorizon(group=group, horizon=h, toks_dev=toks_dev,
+                                idx=idx, occupants=occupants, new=new)
 
     def decode_finish(self,
                       launch: _InFlightHorizon) -> Tuple[np.ndarray, bool]:
         group, h = launch.group, launch.horizon
-        t0 = time.perf_counter()
-        nxt = np.asarray(launch.toks_dev)  # the single device→host sync
-        self.launch_s += time.perf_counter() - t0
+        nxt = self._readback(launch)
         out = np.zeros((group.n_slots, h), np.int32)
         for j, s in enumerate(launch.idx):
             # fold back only slots whose occupant is unchanged since
@@ -1702,7 +1756,7 @@ class ShardedSlotGroup(SlotGroup):
     def _place_fn(self, with_gates: bool):
         fn = self._place_fns.get(with_gates)
         if fn is None:
-            fn = jax.jit(_slot_place_body, donate_argnums=(0, 1, 7),
+            fn = jax.jit(rap_slot_place, donate_argnums=(0, 1, 7),
                          out_shardings=(self._cache_sh, self._tok_sh,
                                         self._rep if with_gates else None))
             self._place_fns[with_gates] = fn

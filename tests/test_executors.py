@@ -135,14 +135,13 @@ def test_report_invariants(served, kind):
     assert rep.ttft["p50"] <= rep.ttft["p90"] + 1e-12 <= rep.ttft["p99"] + 2e-12
     assert rep.itl["count"] >= 8.0            # ≥1 decode token per request
     assert rep.itl["p50"] <= rep.itl["p90"] + 1e-12 <= rep.itl["p99"] + 2e-12
-    # stats() decomposes TTFT into queueing + prefill per request
-    per_req = eng.stats()["requests"]
-    assert set(per_req) == {r.rid for r in done}
-    for rid, d in per_req.items():
-        r = rep.result(rid)
-        assert d["ttft_s"] == r.ttft_s
-        np.testing.assert_allclose(
-            d["queue_delay_s"] + d["prefill_s"], r.ttft_s, atol=1e-9)
+    # the delivery record: its first entry is the first token (TTFT's
+    # anchor), its tokens add up to what the request produced
+    for r in done:
+        assert r.deliveries[0][0] - r.arrival_t == r.ttft_s
+        assert sum(n for _, n in r.deliveries) == r.tokens.shape[1]
+        ts = [t for t, _ in r.deliveries]
+        assert ts == sorted(ts)
     pool = rep.pool
     assert pool["peak_in_use_bytes"] <= pool["peak_reserved_bytes"] + 1e-6
     assert pool["peak_reserved_bytes"] <= pool["capacity_bytes"] + 1e-6
