@@ -301,7 +301,8 @@ def paged_decode_attention(params, cfg, x, kv: dict, page_table, pos, *,
     The new token's K/V is scattered into its owning page (rows own
     disjoint pages, so the scatter is conflict-free), then attention runs
     either through the Pallas paged flash-decode kernel (``impl='pallas'``,
-    the TPU path — BlockSpec index maps chase the page table, no gather)
+    the TPU path — each row's pages are copied by table lookup up to its
+    length, no gather)
     or an XLA gather fallback that materializes ``[B, max_pages ×
     page_tokens]`` and reuses the dense softmax (the CPU serving path).
 

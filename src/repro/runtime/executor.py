@@ -169,6 +169,13 @@ def rap_paged_grant(table, rows, cols, vals):
     return table.at[rows, cols].set(vals)
 
 
+def _hold_free(pos, pos_out):
+    """Positions after a paged horizon: free rows (position 0 — a seated
+    row holds at least its prompt) stay at 0, so their length stays
+    within one horizon and the decode kernel walks them one block."""
+    return jnp.where(pos == 0, pos, pos_out)
+
+
 def rap_slot_place(cache, tokens, req_cache, sidx, plen, first, cols,
                    gates):
     out = {}
@@ -1522,11 +1529,12 @@ class PagedExecutor(ModelExecutor):
                 @functools.partial(jax.jit, donate_argnums=(1, 3, 4))
                 def rap_paged_decode_horizon(p, pools, table, pos, tok,
                                              gates):
-                    toks, pools, pos = decoder.paged_decode_horizon(
+                    toks, pools, pos_out = decoder.paged_decode_horizon(
                         p, cfg, pools, table, pos,
                         tok[:, None], h,
                         gates={"mixer": gates[0], "ffn": gates[1]},
                         impl=impl, layout=layout)
+                    pos = _hold_free(pos, pos_out)
                     return toks, pools, pos, toks[:, -1]
                 fn = rap_paged_decode_horizon
             else:
@@ -1539,7 +1547,7 @@ class PagedExecutor(ModelExecutor):
                         tok[iidx][:, None], h,
                         gates={"mixer": g[0], "ffn": g[1]}, impl=impl,
                         layout=layout)
-                    pos = pos.at[iidx].set(pos_out)
+                    pos = pos.at[iidx].set(_hold_free(pos[iidx], pos_out))
                     tok = tok.at[iidx].set(toks[:, -1])
                     return toks, pools, pos, tok
                 fn = rap_paged_decode_horizon_bucketed
@@ -1632,10 +1640,17 @@ class PagedExecutor(ModelExecutor):
                 -(-min(int(group.pos[s]) + h,
                        self.pool.seq_tokens(group.occupants[s])) // pt)
                 for s in rows)
+            _, _, kv_heads, _, dh = self.pool.k_pages.shape
+            ppb = paged_kernel.pages_per_block(
+                kv_heads, pt, dh, self.pool.k_pages.dtype.itemsize,
+                self.max_row_pages)
+            # each stepped row's length at the horizon's last step (free
+            # rows hold position 0, see _hold_free)
+            walked = paged_kernel.pages_walked(
+                [int(group.pos[s]) + h for s in idx], pt,
+                self.max_row_pages, ppb)
             tr.launch(t=sp.end, horizon=h, rows_stepped=len(idx),
-                      rows_occupied=len(rows),
-                      pages_walked=paged_kernel.pages_walked(
-                          len(idx), self.max_row_pages),
+                      rows_occupied=len(rows), pages_walked=walked,
                       pages_with_tokens=useful)
         return _InFlightHorizon(group=group, horizon=h, toks_dev=toks_dev,
                                 idx=idx, occupants=occupants, new=new)

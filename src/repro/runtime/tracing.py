@@ -47,10 +47,11 @@ class Span(NamedTuple):
 
 
 class Launch(NamedTuple):
-    """One paged decode horizon launch. Pages are counted per kv head and
-    per kernel call (one layer, one step): ``pages_walked`` is what the
-    kernel's grid visits, ``pages_with_tokens`` what the stepped, occupied
-    rows hold at the horizon's last step."""
+    """One paged decode horizon launch. Pages are counted per pool and
+    per kernel call (one layer, one step), at the horizon's last step:
+    ``pages_walked`` is what the kernel copies for the stepped rows
+    (``paged_decode_attention.pages_walked``), ``pages_with_tokens`` what
+    the stepped, occupied rows hold."""
     t: float                          # end of the launch's dispatch span
     horizon: int
     rows_stepped: int

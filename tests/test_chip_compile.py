@@ -55,22 +55,41 @@ def _compiled_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype):
-    """Head-major pages give the K/V blocks full minor dims at any
-    kv-head count (llama2-7b is MHA, K = 32); int8 scales travel as a
-    per-row VMEM block, so SMEM does not grow with the pool."""
-    n_pages = POOL_PAGES[kv_dtype]
-    page_dt = jnp.bfloat16 if kv_dtype == "bf16" else jnp.int8
-    shapes = [((SLOTS, 1, H, D), jnp.bfloat16),
-              ((n_pages, K, PAGE_TOKENS, D), page_dt),
-              ((n_pages, K, PAGE_TOKENS, D), page_dt),
-              ((SLOTS, MAX_LEN // PAGE_TOKENS), jnp.int32),
-              ((SLOTS,), jnp.int32)]
-    if kv_dtype == "bf16":
+# the shapes that run the paged kernel: (arch, rows, kv-pool pages,
+# page-table width). llama2-7b is chip_smoke.py's; glm4-9b the
+# reason_long_kvhalf cell's (64 slots, 7659 pages + the scratch page,
+# 4608-token rows); qwen3-14b the planned chat cell's (32 slots,
+# 2560-token rows)
+PAGED_SHAPES = {
+    "bf16": ("llama2-7b", SLOTS, POOL_PAGES["bf16"], MAX_LEN // PAGE_TOKENS),
+    "int8": ("llama2-7b", SLOTS, POOL_PAGES["int8"], MAX_LEN // PAGE_TOKENS),
+    "glm4-9b-bf16": ("glm4-9b", 64, 7659 + 1, 4608 // PAGE_TOKENS),
+    "glm4-9b-int8": ("glm4-9b", 64, 2 * 7659 + 1, 4608 // PAGE_TOKENS),
+    "qwen3-14b-bf16": ("qwen3-14b", 32, 4294 + 1, 2560 // PAGE_TOKENS),
+    "qwen3-14b-int8": ("qwen3-14b", 32, 2 * 4294 + 1, 2560 // PAGE_TOKENS),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_SHAPES))
+def test_paged_decode_compiles_for_v5e(one_chip, case):
+    """One kernel for every kv-head count and page dtype: head-major
+    pages travel as one DMA each into VMEM blocks of
+    ``pages_per_block`` pages; int8 scales travel as a per-row VMEM
+    block, so SMEM does not grow with the pool (llama2-7b K=32, G=1;
+    glm4-9b K=2, G=16; qwen3-14b K=8, G=5)."""
+    arch, slots, n_pages, max_pages = PAGED_SHAPES[case]
+    cfg = get_config(arch)
+    h, k, d = cfg.n_heads, cfg.n_kv_heads, cfg.dh
+    page_dt = jnp.int8 if case.endswith("int8") else jnp.bfloat16
+    shapes = [((slots, 1, h, d), jnp.bfloat16),
+              ((n_pages, k, PAGE_TOKENS, d), page_dt),
+              ((n_pages, k, PAGE_TOKENS, d), page_dt),
+              ((slots, max_pages), jnp.int32),
+              ((slots,), jnp.int32)]
+    if page_dt == jnp.bfloat16:
         fn = pdec.paged_decode_attention
     else:
-        shapes += [((n_pages, K), jnp.float32)] * 2
+        shapes += [((n_pages, k), jnp.float32)] * 2
 
         def fn(q, kp, vp, table, lengths, ks, vs):
             return pdec.paged_decode_attention(q, kp, vp, table, lengths,

@@ -6,6 +6,7 @@ import pytest
 from numpy.testing import assert_allclose
 
 from repro.kernels import ops, ref
+from repro.kernels import paged_decode_attention as pdec
 
 # every test here exercises Pallas kernels in interpret mode — the
 # `pallas-interpret` CI job runs this module under JAX_PLATFORMS=cpu so
@@ -58,36 +59,66 @@ def test_decode_attention(B, H, K, D, S, nvalid):
 
 
 PAGED_CASES = [
-    # B, H, K, D, page_tokens, max_len, softcap
-    (3, 8, 2, 64, 16, 80, 0.0),
-    (2, 4, 4, 32, 8, 64, 0.0),       # MHA, small pages
-    (1, 16, 2, 64, 32, 96, 0.0),     # wide GQA group
-    (2, 6, 3, 16, 16, 48, 30.0),     # non-pow2 heads + softcap
+    # B, H, K, D, page_tokens, max_len, softcap, page dtype, lengths
+    # (None: random in [1, max_len])
+    (3, 8, 2, 64, 16, 80, 0.0, np.float32, None),
+    (2, 4, 4, 32, 8, 64, 0.0, np.float32, None),    # MHA, small pages
+    (1, 16, 2, 64, 32, 96, 0.0, np.float32, None),  # wide GQA group
+    (2, 6, 3, 16, 16, 48, 30.0, np.float32, None),  # non-pow2 heads + softcap
+    # 8 pages (128 tokens) a block, 20 pages a row: the last block holds
+    # 4 table columns. Rows of 0 and 1 tokens, one on a block boundary,
+    # partial last blocks, and one at max_len
+    (6, 8, 2, 64, 16, 320, 0.0, np.float32, (0, 1, 128, 200, 320, 129)),
+    # bf16 pages of 4 heads: 4 pages a block, 10 a row
+    (4, 8, 4, 128, 16, 160, 30.0, jnp.bfloat16, (1, 64, 160, 100)),
 ]
 
 
-@pytest.mark.parametrize("B,H,K,D,pt,S,cap", PAGED_CASES)
-def test_paged_decode_matches_dense_bitwise(B, H, K, D, pt, S, cap):
-    """Paged kernel == dense decode kernel, BITWISE, on random GQA shapes.
-
-    With ``page_tokens == block_k`` and pages holding the same tokens in
-    order, both kernels run the identical f32 online-softmax op sequence —
-    page indirection must not change a single ulp. Rows get random lengths
-    (ragged batch) and pages are scattered randomly through the pool."""
-    rng = np.random.default_rng(B * 1000 + S)
+def _paged_case(rng, B, H, K, D, pt, S, lengths, dt):
+    """A ragged batch whose rows' tokens sit in randomly scattered pages
+    of a pool with spare garbage pages: (q, contiguous k, v [B, S, K, D],
+    k/v pages, page table, lengths)."""
     P = -(-S // pt)                       # pages per row
     n_pages = B * P + 3                   # spare pages stay garbage
-    lengths = rng.integers(1, S + 1, size=B).astype(np.int32)
+    if lengths is None:
+        lengths = rng.integers(1, S + 1, size=B)
+    lengths = np.asarray(lengths, np.int32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)).astype(np.float32))
-    kd = rng.standard_normal((B, S, K, D)).astype(np.float32)
-    vd = rng.standard_normal((B, S, K, D)).astype(np.float32)
+    kd = rng.standard_normal((B, S, K, D)).astype(dt)
+    vd = rng.standard_normal((B, S, K, D)).astype(dt)
     table = rng.permutation(n_pages)[: B * P].reshape(B, P).astype(np.int32)
-    k_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
-    v_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
+    k_pages = rng.standard_normal((n_pages, K, pt, D)).astype(dt)
+    v_pages = rng.standard_normal((n_pages, K, pt, D)).astype(dt)
     for b in range(B):
         for p in range(P):       # head-major pages: [K, pt, D] per page
             k_pages[table[b, p]] = kd[b, p * pt:(p + 1) * pt].swapaxes(0, 1)
             v_pages[table[b, p]] = vd[b, p * pt:(p + 1) * pt].swapaxes(0, 1)
+    return q, kd, vd, k_pages, v_pages, table, lengths
+
+
+def _block_tokens(K, D, pt, S, dt):
+    """The paged kernel's tokens per block for this page shape."""
+    return pdec.pages_per_block(K, pt, D, np.dtype(dt).itemsize,
+                                -(-S // pt)) * pt
+
+
+@pytest.mark.parametrize("B,H,K,D,pt,S,cap,dt,lengths", PAGED_CASES)
+def test_paged_decode_matches_dense_bitwise(B, H, K, D, pt, S, cap, dt,
+                                            lengths):
+    """Paged kernel == dense decode kernel, BITWISE, on random GQA shapes.
+
+    With the dense kernel's ``block_k`` equal to the paged kernel's
+    block (``pages_per_block × page_tokens``) and pages holding the same
+    tokens in order, both kernels run the identical f32 online-softmax op
+    sequence — page indirection, the walk ending at each row's length and
+    the last block's re-copied pages must not change a single ulp. Rows
+    get random or given lengths (ragged batch) and pages are scattered
+    randomly through the pool."""
+    rng = np.random.default_rng(B * 1000 + S)
+    q, kd, vd, k_pages, v_pages, table, lengths = _paged_case(
+        rng, B, H, K, D, pt, S, lengths, dt)
+    bk = _block_tokens(K, D, pt, S, dt)
+    assert bk <= 128              # the dense kernel's largest block
 
     out = ops.paged_decode_attention(q, jnp.asarray(k_pages),
                                      jnp.asarray(v_pages),
@@ -97,40 +128,95 @@ def test_paged_decode_matches_dense_bitwise(B, H, K, D, pt, S, cap):
         valid = jnp.arange(S) < lengths[b]
         want = ops.decode_attention(q[b:b + 1], jnp.asarray(kd[b:b + 1]),
                                     jnp.asarray(vd[b:b + 1]), valid,
-                                    softcap=cap, block_k=pt)
+                                    softcap=cap, block_k=bk)
         np.testing.assert_array_equal(np.asarray(out[b]), np.asarray(want[0]))
 
 
-@pytest.mark.parametrize("B,H,K,D,pt,S,cap", PAGED_CASES)
+@pytest.mark.parametrize("B,H,K,D,pt,S,cap,dt,lengths", [
+    c for c in PAGED_CASES if _block_tokens(*c[2:6], np.int8) <= 128])
 def test_paged_decode_quantized_matches_dequant_bitwise(B, H, K, D, pt, S,
-                                                        cap):
-    """Fused-dequant kernel == fp32 kernel on externally dequantized pages,
+                                                        cap, dt, lengths):
+    """Fused-dequant kernel == fp32 math on externally dequantized pages,
     BITWISE. The quantized kernel widens each int8 page to f32 and applies
-    the per-(page, kv-head) scale BEFORE the shared flash step, so it must
-    reproduce the exact op sequence of the fp32 kernel fed
-    ``page_dequant``-ed pages — which in turn is bitwise vs the dense
-    decode kernel (pinned above). This is the pin that lets the XLA gather
-    fallback and the Pallas path share one numeric contract."""
+    the per-(page, kv-head) scale BEFORE the shared online-softmax update,
+    so it must reproduce the exact op sequence of a kernel fed
+    ``page_dequant``-ed pages in blocks of the same size: the fp32 paged
+    kernel where both walks have one block per row, else the dense decode
+    kernel (pinned bitwise to the paged one above) with the int8 walk's
+    block. Pages are built in f32 whatever ``dt``. This is the pin that
+    lets the XLA gather fallback and the Pallas path share one numeric
+    contract."""
     from repro.models.attention import page_dequant, page_quant
     rng = np.random.default_rng(B * 777 + S)
-    P = -(-S // pt)
-    n_pages = B * P + 3
-    lengths = rng.integers(1, S + 1, size=B).astype(np.int32)
-    q = jnp.asarray(rng.standard_normal((B, 1, H, D)).astype(np.float32))
-    table = rng.permutation(n_pages)[: B * P].reshape(B, P).astype(np.int32)
-    k_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
-    v_pages = rng.standard_normal((n_pages, K, pt, D)).astype(np.float32)
+    q, _, _, k_pages, v_pages, table, lengths = _paged_case(
+        rng, B, H, K, D, pt, S, lengths, np.float32)
     kq, ks = page_quant(jnp.asarray(k_pages), jnp.int8)
     vq, vs = page_quant(jnp.asarray(v_pages), jnp.int8)
+    kf, vf = page_dequant(kq, ks), page_dequant(vq, vs)
 
     out = ops.paged_decode_attention(q, kq, vq, jnp.asarray(table),
                                      jnp.asarray(lengths), softcap=cap,
                                      k_scales=ks, v_scales=vs)
-    want = ops.paged_decode_attention(q, page_dequant(kq, ks),
-                                      page_dequant(vq, vs),
-                                      jnp.asarray(table),
-                                      jnp.asarray(lengths), softcap=cap)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    bk = _block_tokens(K, D, pt, S, np.int8)
+    if bk == _block_tokens(K, D, pt, S, np.float32):
+        want = ops.paged_decode_attention(q, kf, vf, jnp.asarray(table),
+                                          jnp.asarray(lengths), softcap=cap)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        return
+    for b in range(B):           # the row's tokens, contiguous [1, S, K, D]
+        rows = lambda pages: jnp.swapaxes(pages[table[b]], 1, 2).reshape(
+            1, -1, K, D)[:, :S]
+        want = ops.decode_attention(q[b:b + 1], rows(kf), rows(vf),
+                                    jnp.arange(S) < lengths[b],
+                                    softcap=cap, block_k=bk)
+        np.testing.assert_array_equal(np.asarray(out[b]), np.asarray(want[0]))
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_decode_never_reads_past_a_rows_length(quantized):
+    """Table entries past each row's last page hold out-of-range ids or
+    point at a page of NaNs: the output is bitwise what a clean table
+    gives, so the walk never copies those pages (a copied NaN value row
+    would poison the row even under a zero softmax weight)."""
+    from repro.models.attention import page_quant
+    B, H, K, D, pt, S, _, dt, lengths = PAGED_CASES[4]
+    rng = np.random.default_rng(11)
+    q, _, _, k_pages, v_pages, table, lengths = _paged_case(
+        rng, B, H, K, D, pt, S, lengths, dt)
+    nan_page = min(set(range(k_pages.shape[0])) - set(table.ravel()))
+    k_pages[nan_page] = np.nan
+    v_pages[nan_page] = np.nan
+    dirty = table.copy()
+    for b, n in enumerate(lengths):
+        tail = dirty[b, -(-n // pt):]
+        tail[:] = rng.choice([nan_page, -7, 10 ** 6], size=tail.shape)
+    kw = {}
+    kp, vp = jnp.asarray(k_pages), jnp.asarray(v_pages)
+    if quantized:            # int8 codes cannot hold a NaN: its scales do
+        kp, ks = page_quant(kp, jnp.int8)
+        vp, vs = page_quant(vp, jnp.int8)
+        kw = {"k_scales": ks.at[nan_page].set(np.nan),
+              "v_scales": vs.at[nan_page].set(np.nan)}
+    clean = ops.paged_decode_attention(q, kp, vp, jnp.asarray(table),
+                                       jnp.asarray(lengths), **kw)
+    out = ops.paged_decode_attention(q, kp, vp, jnp.asarray(dirty),
+                                     jnp.asarray(lengths), **kw)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
+def test_paged_walk_count_follows_the_block():
+    """``pages_walked`` per row: its pages, at most the table's, rounded
+    up to whole blocks; a row of length 0 copies nothing."""
+    lengths = (0, 1, 128, 129, 200, 320, 400)   # 16-token pages, 8 a block
+    assert pdec.pages_walked(lengths, 16, 20, 8) == (0 + 8 + 8 + 16 + 16
+                                                     + 24 + 24)
+    assert pdec.pages_per_block(2, 16, 128, 2, 288) == 8    # glm4-9b bf16
+    assert pdec.pages_per_block(2, 16, 128, 1, 288) == 16   # glm4-9b int8
+    assert pdec.pages_per_block(32, 16, 128, 2, 16) == 8    # llama2-7b bf16
+    assert pdec.pages_per_block(32, 16, 128, 4, 16) == 4    # VMEM-bound
+    assert pdec.pages_per_block(64, 16, 128, 4, 16) == 2
+    assert pdec.pages_per_block(2, 16, 128, 2, 4) == 4      # capped at table
 
 
 def test_paged_decode_row_isolation():

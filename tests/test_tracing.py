@@ -113,32 +113,57 @@ def test_dispatch_and_readback_add_up_to_launch_s(runs, kind):
     assert timed == pytest.approx(launch_s, rel=0.01)
 
 
-def test_page_walk_counts_match_a_hand_count(tiny_model):
-    """Two rows of 5 and 20 tokens in a four-slot group stepped at full
-    width, 8-token pages, a 64-token table: the kernel walks 4 × 8 pages
-    per head, and after a 4-token horizon the rows hold ⌈9/8⌉ + ⌈24/8⌉
-    pages."""
-    model, params, batch = tiny_model
+def _walk_record(tiny_model, max_len, prompts, horizon=4):
+    """Seat one request per prompt length in a four-slot paged group
+    (8-token pages), step the group one horizon at full width, and
+    return the launch's record."""
+    model, params, _ = tiny_model
     full = masks.full_mask(model.cfg.n_layers)
-    toks = np.asarray(batch["tokens"])
+    toks = np.random.default_rng(0).integers(
+        0, model.cfg.vocab_size, (1, max(prompts)), dtype=np.int32)
     ex = PagedExecutor(model, params, max_active=4, decode_buckets=())
     pt = 8
     page_bytes = ex.page_phys_bytes(pt)
-    pool = KVPool(32 * page_bytes, page_bytes=page_bytes,
+    pool = KVPool(160 * page_bytes, page_bytes=page_bytes,
                   tokens_per_page=pt)
-    ex.bind_pool(pool, max_len=64)
+    ex.bind_pool(pool, max_len=max_len)
     group = ex.group_for(full, 0)
-    for slot, (rid, n) in enumerate((("a", 5), ("b", 20))):
-        pool.alloc_tokens(rid, 1, n, max_tokens=n + 16)
-        ex.prefill_into(group, [slot], rid, toks[:1, :n], full)
-    ex.decode_finish(ex.decode_launch(group, 4))
+    for slot, n in enumerate(prompts):
+        pool.alloc_tokens(f"r{slot}", 1, n, max_tokens=n + 16)
+        ex.prefill_into(group, [slot], f"r{slot}", toks[:, :n], full)
+    ex.decode_finish(ex.decode_launch(group, horizon))
     (rec,) = ex.tracer.launches
+    assert ex.tracer.counter_totals["launch.pages_walked"] == rec.pages_walked
+    # seated rows advance; free rows stay at position 0 on the device
+    assert np.asarray(group.pos_dev).tolist() == (
+        [n + horizon for n in prompts] + [0] * (4 - len(prompts)))
+    for slot in range(len(prompts)):
+        pool.free(f"r{slot}")
+    return rec
+
+
+def test_page_walk_counts_match_a_hand_count(tiny_model):
+    """Two rows of 5 and 20 tokens and two free rows in a four-slot group
+    stepped at full width, 8-token pages, a 64-token table. The tiny
+    model's 2 KB pages (4 kv heads × 8 tokens × 16 dims, f32) make a
+    block of 32 pages, capped at the table's 8: after a 4-token horizon
+    each row — 9, 24, 4 and 4 tokens — copies one block of 8 pages, and
+    the two requests hold ⌈9/8⌉ + ⌈24/8⌉ pages."""
+    rec = _walk_record(tiny_model, 64, (5, 20))
     assert (rec.horizon, rec.rows_stepped, rec.rows_occupied) == (4, 4, 2)
-    assert rec.pages_walked == 4 * 8
+    assert rec.pages_walked == 8 + 8 + 8 + 8
     assert rec.pages_with_tokens == 2 + 3
-    assert ex.tracer.counter_totals["launch.pages_walked"] == 32
-    for rid in ("a", "b"):
-        pool.free(rid)
+
+
+def test_page_walk_counts_a_long_row_beside_short_ones(tiny_model):
+    """A 600-token row beside rows of 5 and 20 tokens and one free row,
+    in a 1024-token table (128 pages, four blocks of 32): after a 4-token
+    horizon the long row's 604 tokens fill 76 pages and copy three
+    blocks; each other row copies one."""
+    rec = _walk_record(tiny_model, 1024, (600, 5, 20))
+    assert (rec.rows_stepped, rec.rows_occupied) == (4, 3)
+    assert rec.pages_walked == 96 + 32 + 32 + 32
+    assert rec.pages_with_tokens == 76 + 2 + 3
 
 
 def test_spans_reach_the_profiler_host_plane(served, tmp_path):
