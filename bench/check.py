@@ -3,7 +3,8 @@
 After the window has closed and the program's state is freed, a sample of
 the requests the window served, drawn from the seed and always holding the
 one with the most served tokens, is run through the float32 reference
-(``bench/reference.py``), teacher forced over prompt + served tokens with
+of the configuration's family (``bench/families/<family>.py`` on
+``bench/reference.py``), teacher forced over prompt + served tokens with
 each request's own keep-mask as gates. For every served token the gap
 between the reference's best logit and the served token's logit at the
 position that produced it is read; the widest gap over the sample is held
@@ -59,13 +60,13 @@ def readings(cell, seed: int, items: List[Item],
              control: bool = False) -> Dict[str, Any]:
     """The reference's gaps for ``items`` (weights rebuilt from the seed);
     with ``control`` those of the fp8 control put in the program's place
-    (``reference.control_gaps``)."""
+    (``reference.control_gaps`` through the family's ``hidden``)."""
     import jax
-    m = cell.config["model"]
+    m, fam = cell.config["model"], cell.family
     key = jax.random.key(serve.seed32(seed))
-    w = jax.jit(lambda k: reference.init_weights(m, k))(key)
+    w = jax.jit(lambda k: fam.init_weights(m, k))(key)
     gaps_fn = reference.control_gaps if control else reference.served_gaps
-    gaps = gaps_fn(m, w, [(p, s, k) for _, p, s, k in items])
+    gaps = gaps_fn(m, w, [(p, s, k) for _, p, s, k in items], fam.hidden)
     del w
     allg = np.concatenate(gaps) if gaps else np.zeros((0,))
     valid = all(int(s.min()) >= 0 and int(s.max()) < m["vocab_size"]

@@ -2,11 +2,9 @@
 model FLOPs against the peak, the paged decode kernel against its
 roofline, and the device's idle share. The work is what the tokens
 delivered and the prompt tokens prefilled between the marks taken just
-after the profiler started and just before it stopped need
-(``bench/flops.py``); the times are the trace's own: its window, its busy
-time and the kernel's summed op time."""
-from bench import flops
-
+after the profiler started and just before it stopped need, by the counts
+of the configuration's family module; the times are the trace's own: its
+window, its busy time and the kernel's summed op time."""
 KERNEL = "rap_paged_decode_attention"
 MARKS = ("trace_open", "trace_close")
 
@@ -15,9 +13,9 @@ def mfu(ctx):
     if ctx.trace is None or ctx.trace["window_s"] <= 0:
         return None
     dec, ctx_dec, pre, ctx_pre = ctx.work(*MARKS)
-    cfg = ctx.config
-    work = ((dec + pre) * flops.matmul_flops_per_token(cfg)
-            + flops.attn_flops(cfg, ctx_dec + ctx_pre))
+    cfg, fam = ctx.config, ctx.cell.family
+    work = ((dec + pre) * fam.matmul_flops_per_token(cfg)
+            + fam.attn_flops(cfg, ctx_dec + ctx_pre))
     if work <= 0:
         return None
     return 100.0 * work / (ctx.trace["window_s"]
@@ -32,12 +30,10 @@ def paged_attn_roofline(ctx):
     dec, ctx_dec, _, _ = ctx.work(*MARKS)
     if kt <= 0 or dec <= 0:
         return None
-    cfg = ctx.config
-    m = cfg["model"]
-    need_bytes = (flops.decode_attn_bytes(cfg, 0.0) * dec
-                  + 2.0 * m["n_kv_heads"] * m["head_dim"] * 2
-                  * m["n_layers"] * ctx_dec)
-    need_flops = flops.attn_flops(cfg, ctx_dec)
+    cfg, fam = ctx.config, ctx.cell.family
+    need_bytes = (fam.decode_attn_bytes(cfg, 0.0) * dec
+                  + fam.kv_bytes_per_ctx_token(cfg) * ctx_dec)
+    need_flops = fam.attn_flops(cfg, ctx_dec)
     t_bytes = need_bytes / ctx.peaks["hbm_bytes_per_s"]
     t_flops = need_flops / ctx.peaks["bf16_flops_per_s"]
     return 100.0 * max(t_bytes, t_flops) / kt

@@ -16,65 +16,31 @@ import numpy as np
 
 from bench import reference
 
+
 def seed32(seed: int) -> int:
     """A 32-bit draw of any whole-number seed (JAX keys take 32 bits)."""
     return int(np.random.SeedSequence(int(seed)).generate_state(1)[0])
 
 
-def model_config(cell_cfg: Dict[str, Any]):
-    """The registry's configuration with the file's overrides, checked
-    against the widths the file states."""
+def model_config(cell):
+    """The registry's configuration with the file's overrides, checked by
+    the cell's family against the widths the file states."""
     from repro.configs import get_config
-    cfg = get_config(cell_cfg["arch"]).replace(**cell_cfg["overrides"])
-    m = cell_cfg["model"]
-    got = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-           "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-           "head_dim": cfg.dh, "d_ff": cfg.d_ff,
-           "vocab_size": cfg.vocab_size, "vocab_padded": cfg.vocab_padded,
-           "qkv_bias": cfg.qkv_bias, "qk_norm": cfg.qk_norm,
-           "rope_theta": float(cfg.rope_theta), "norm_eps": cfg.norm_eps,
-           "tie_embeddings": cfg.tie_embeddings, "dtype": cfg.dtype}
-    bad = {k: (got[k], m[k]) for k in got if got[k] != m[k]}
-    if bad or cfg.activation != "swiglu" or not cfg.is_uniform():
-        raise ValueError(f"{cell_cfg['name']}: the registry's config "
-                         f"differs from the file's model block: {bad}")
+    c = cell.config
+    cfg = get_config(c["arch"]).replace(**c["overrides"])
+    cell.family.check(cfg, c["model"])
     return cfg
 
 
-def _perturb_params(params, k_pert, vocab_size: int):
-    """The benchmark's perturbation, on the program's parameter tree: the
-    norm scales, q/k/v biases and qk-norm scales that init leaves at zero
-    are drawn, and padded vocabulary rows/columns are zeroed (a padded id
-    is then never the best logit, as in a trained checkpoint)."""
-    import jax
-    names = {("stacks", "attn", "norm", "scale"), ("stacks", "dense", "norm",
-                                                   "scale"),
-             ("final_norm", "scale"), ("stacks", "attn", "bq"),
-             ("stacks", "attn", "bk"), ("stacks", "attn", "bv"),
-             ("stacks", "attn", "q_norm"), ("stacks", "attn", "k_norm")}
-
-    def leaf(path, x):
-        keys = tuple(getattr(p, "key", None) for p in path)
-        if keys in names:
-            return reference.perturbed(k_pert, "/".join(keys), x.shape)
-        return x
-
-    params = jax.tree_util.tree_map_with_path(leaf, params)
-    vp = params["embed"].shape[0]
-    if vp > vocab_size:
-        params["embed"] = params["embed"].at[vocab_size:].set(0)
-        params["lm_head"] = params["lm_head"].at[:, vocab_size:].set(0)
-    return params
-
-
-def make_params(model, seed: int):
-    """The served weights, drawn on the device in one jitted call."""
+def make_params(family, model, seed: int):
+    """The served weights, drawn on the device in one jitted call and
+    perturbed as ``family`` says."""
     import jax
     vocab = model.cfg.vocab_size
 
     def draw(key):
         k_init, k_pert = reference.split_seed_key(key)
-        return _perturb_params(model.init(k_init), k_pert, vocab)
+        return family.perturb(model.init(k_init), k_pert, vocab)
 
     params = jax.jit(draw)(jax.random.key(seed32(seed)))
     jax.block_until_ready(params)
@@ -96,9 +62,9 @@ def build(cell, seed: int, budget: float, kv_dtype=None):
     from repro.core.policy import RLPolicy
     from repro.models import registry
     from repro.runtime import EngineConfig, PagedExecutor, RAPEngine
-    cfg = model_config(cell.config)
+    cfg = model_config(cell)
     model = registry.build(cfg)
-    params = make_params(model, seed)
+    params = make_params(cell.family, model, seed)
     e = cell.mix["engine"]
     kv = kv_dtype or e["kv_dtype"]
     mm = memory.build_memory_model(cfg)

@@ -53,7 +53,7 @@ def smoke_budget(cell) -> float:
     """Weights plus room for every slot's pages at ``max_len``."""
     from bench import serve
     from repro.core import masks, memory
-    cfg = serve.model_config(cell.config)
+    cfg = serve.model_config(cell)
     mm = memory.build_memory_model(cfg)
     e = cell.mix["engine"]
     full = masks.full_mask(cfg.n_layers)

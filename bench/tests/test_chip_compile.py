@@ -1,8 +1,10 @@
-"""Compile the cells' paged decode kernel and serving steps for a described
-TPU v5e (no chip attached): the kernel at each cell's page-table width
-(glm4-9b: K=2, G=16), and the full-width decode horizon and a one-token
-prefill chunk at each cell's pool size, which have to fit the chip's
-memory beside the weights."""
+"""Compile the dense GQA cells' paged decode kernel and serving steps for a
+described TPU v5e (no chip attached): the kernel at each cell's page-table
+width (glm4-9b: K=2, G=16), and the full-width decode horizon and a
+one-token prefill chunk at each cell's pool size, which have to fit the
+chip's memory beside the weights. The pools here are the family's K and V
+pages. A cell of another family is listed in ``CELLS`` of that family's
+own ``test_chip_compile_<family>.py``; one that is not fails here."""
 import os
 
 import pytest
@@ -12,7 +14,26 @@ from bench import spec
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 BYTES_LIMIT = 16909336064          # bytes_limit one v5e chip reports
-CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
+_BM = spec.benchmark()
+_FAMILY = {c["name"]: spec.load_json(os.path.join(spec.ROOT, c["file"]))
+           .get("family") for c in _BM["configs"]}
+_CELL_FAMILY = {w["name"]: _FAMILY[w["config"]] for w in _BM["workloads"]}
+CELLS = [c for c, f in _CELL_FAMILY.items() if f == "dense_gqa"]
+
+
+@pytest.mark.parametrize("cell_name", sorted(_CELL_FAMILY))
+def test_every_cell_has_a_v5e_compile_test(cell_name):
+    family = _CELL_FAMILY[cell_name]
+    if family == "dense_gqa":
+        assert cell_name in CELLS
+        return
+    path = os.path.join(spec.BENCH_DIR, "tests",
+                        f"test_chip_compile_{family}.py")
+    assert os.path.isfile(path), \
+        f"{cell_name}: family {family} has no compile test {path}"
+    mod = spec._load(path, f"bench_compile_test_{family}")
+    assert cell_name in getattr(mod, "CELLS", ()), \
+        f"{cell_name} is not in CELLS of {path}"
 
 
 @pytest.fixture(scope="module")
@@ -38,14 +59,15 @@ def _shapes(cell_name, sharding):
     from repro.core import masks, memory
     from repro.models import registry
     cell = spec.resolve(cell_name)
-    cfg = serve.model_config(cell.config)
+    cfg = serve.model_config(cell)
     model = registry.build(cfg)
     e = cell.mix["engine"]
     mm = memory.build_memory_model(cfg)
     budget = serve.device_budget(e, BYTES_LIMIT)
     L, K, D, pt = cfg.n_layers, cfg.n_kv_heads, cfg.dh, 16
+    page_bytes = pt * cell.family.kv_bytes_per_ctx_token(cell.config)
     n_pages = int((budget - mm.param_bytes(masks.full_mask(L)))
-                  // (2 * L * pt * K * D * 2))
+                  // page_bytes)
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     params = jax.tree.map(lambda s: S(s.shape, s.dtype),
                           jax.eval_shape(model.init, jax.random.key(0)))

@@ -10,7 +10,8 @@ from bench import flops, spec
 def _ctx(marks, prompt_len, cfg_name="glm4-9b-l20"):
     from bench.run import Context, _request_work, delivered
     cfg = spec.load_json(f"{spec.BENCH_DIR}/configs/{cfg_name}.json")
-    ctx = Context(marks=marks, prompt_len=prompt_len, config=cfg)
+    ctx = Context(marks=marks, prompt_len=prompt_len, config=cfg,
+                  cell=types.SimpleNamespace(family=spec.family_module(cfg)))
     ctx.delivered = lambda a, b: delivered(ctx, a, b)
     ctx.work = lambda a, b: _request_work(ctx, a, b)
     return ctx
@@ -41,15 +42,16 @@ def test_output_rate_and_host_share_over_the_window():
 
 def test_flop_counts_match_the_published_sizes():
     cfg = spec.load_json(f"{spec.BENCH_DIR}/configs/glm4-9b-l20.json")
+    fam = spec.family_module(cfg)
     # 20 layers of q/k/v/o and the GLU FFN, and the untied head
-    assert flops.layer_params(cfg) == 4096 * (4096 + 2 * 256) + 4096 * \
+    assert fam.layer_params(cfg) == 4096 * (4096 + 2 * 256) + 4096 * \
         4096 + 3 * 4096 * 13696
-    assert flops.matmul_flops_per_token(cfg) == pytest.approx(
-        2 * (20 * flops.layer_params(cfg) + 4096 * 151552))
+    assert fam.matmul_flops_per_token(cfg) == pytest.approx(
+        2 * (20 * fam.layer_params(cfg) + 4096 * 151552))
     # one token at ctx 1000: K and V of 1000 tokens, 2 kv heads, 20 layers
-    assert flops.decode_attn_bytes(cfg, 1000) == pytest.approx(
+    assert fam.decode_attn_bytes(cfg, 1000) == pytest.approx(
         (2 * 1000 * 2 * 128 * 2 + 2 * 32 * 128 * 2) * 20)
-    assert flops.attn_flops(cfg, 1000) == 4 * 1000 * 32 * 128 * 20
+    assert fam.attn_flops(cfg, 1000) == 4 * 1000 * 32 * 128 * 20
     for p, a, b in ((100, 0, 50), (7, 3, 4), (5, 2, 2)):
         assert flops.sum_ctx(p, a, b) == sum(p + i
                                              for i in range(max(a, 1), b))
@@ -73,12 +75,14 @@ def test_device_shares_from_a_reduced_trace():
     ctx.peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     ctx.trace = {"kernel_s": {"rap_paged_decode_attention": 0.5},
                  "busy_s": 1.5, "window_s": 2.0}
-    need = sum(flops.decode_attn_bytes(ctx.config, 990 + i)
+    fam = ctx.cell.family
+    need = sum(fam.decode_attn_bytes(ctx.config, 990 + i)
                for i in range(1, 11))
     share = spec.metric_module("paged_attn_roofline.decode").compute(ctx)
     assert share == pytest.approx(100 * need / 819e9 / 0.5)
     assert spec.metric_module("device_idle_share.decode").compute(ctx) == \
         pytest.approx(0.25)
-    work = sum(flops.token_flops(ctx.config, 990 + i) for i in range(1, 11))
+    work = sum(fam.matmul_flops_per_token(ctx.config)
+               + fam.attn_flops(ctx.config, 990 + i) for i in range(1, 11))
     assert spec.metric_module("mfu.decode").compute(ctx) == \
         pytest.approx(100 * work / (2.0 * 197e12))

@@ -1,5 +1,6 @@
-"""The plain reference: it rebuilds the served weights from the seed
-bitwise, and in float32 it computes what the program's forward computes."""
+"""The plain reference of the cell's family: it rebuilds the served weights
+from the seed bitwise, and in float32 it computes what the program's
+forward computes."""
 import copy
 
 import jax
@@ -7,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, serve
+from bench import check, reference, serve
+from bench.families import dense_gqa
 from bench.tests.smoke import smoke_cell
 
 CELL = "glm4-9b.reason_long_kvhalf"
@@ -27,8 +29,8 @@ def _variant(qk_norm: bool):
 
 def _program(cell):
     from repro.models import registry
-    model = registry.build(serve.model_config(cell.config))
-    return model, serve.make_params(model, SEED)
+    model = registry.build(serve.model_config(cell))
+    return model, serve.make_params(cell.family, model, SEED)
 
 
 @pytest.mark.parametrize("qk_norm", [False, True])
@@ -36,7 +38,7 @@ def test_weights_are_rebuilt_bitwise(qk_norm):
     cell = _variant(qk_norm)
     model, p = _program(cell)
     m = cell.config["model"]
-    w = reference.init_weights(m, jax.random.key(serve.seed32(SEED)))
+    w = cell.family.init_weights(m, jax.random.key(serve.seed32(SEED)))
     a, f = p["stacks"]["attn"], p["stacks"]["dense"]
     pairs = [(w["embed"], p["embed"]), (w["head"], p["lm_head"]),
              (w["wq"], a["wq"]), (w["wk"], a["wk"]), (w["wv"], a["wv"]),
@@ -66,7 +68,7 @@ def test_reference_matches_the_program_forward_in_f32(qk_norm):
     cell32 = type(cell)(**{**cell.__dict__, "config": cfg32})
     model, p = _program(cell32)
     m = cell.config["model"]
-    w = reference.init_weights(m, jax.random.key(serve.seed32(SEED)))
+    w = cell.family.init_weights(m, jax.random.key(serve.seed32(SEED)))
     L = m["n_layers"]
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, m["vocab_size"], 40).astype(np.int32)
@@ -80,5 +82,34 @@ def test_reference_matches_the_program_forward_in_f32(qk_norm):
                                  "ffn": jnp.asarray(mask[L:], jnp.float32)})
     lg = np.asarray(lg[0, len(prompt) - 1:, :m["vocab_size"]])
     want = lg.max(-1) - lg[np.arange(len(served)), served]
-    got = reference.served_gaps(m, w, [(prompt, served, mask)])[0]
+    got = reference.served_gaps(m, w, [(prompt, served, mask)],
+                                cell.family.hidden)[0]
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-4)
+
+
+def test_the_cells_family_is_the_moved_reference_bit_for_bit():
+    """Served and control gaps read through ``cell.family`` (as the check
+    reads them) equal the shared gap loop on ``dense_gqa.hidden`` called
+    directly on the same seed, bit for bit."""
+    cell = smoke_cell(CELL)
+    m = cell.config["model"]
+    L = m["n_layers"]
+    rng = np.random.default_rng(1)
+    items = []
+    for S, n in ((40, 9), (23, 17)):
+        mask = np.ones(2 * L, bool)
+        mask[rng.integers(2 * L)] = False
+        items.append((f"r{S}", rng.integers(0, m["vocab_size"], S),
+                      rng.integers(0, m["vocab_size"], n), mask))
+    plain = [(p, s, k) for _, p, s, k in items]
+    key = jax.random.key(serve.seed32(SEED))
+    w = jax.jit(lambda k: dense_gqa.init_weights(m, k))(key)
+    for control, gaps in ((False, reference.served_gaps),
+                          (True, reference.control_gaps)):
+        want = np.concatenate(gaps(m, w, plain, dense_gqa.hidden))
+        got = check.readings(cell, SEED, items, control=control)
+        assert got["max_logit_gap"] == float(want.max())
+        assert got["median_logit_gap"] == float(np.median(want))
+        assert got["tokens_compared"] == want.size
+        np.testing.assert_array_equal(
+            np.concatenate(gaps(m, w, plain, cell.family.hidden)), want)
